@@ -6,10 +6,6 @@ multi-scene pipeline (table_2b): per-scene latency for B scenes focused in
 one batched dispatch sequence vs B=1, using the autotuned kernel config."""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -66,75 +62,38 @@ def run_batched(cfg, raw, variant: str = "fused3", batches=(1, 4),
     return t1
 
 
-# table_8 (sharded megakernel) runs in a subprocess: the host-platform
-# device-count flag must land in XLA_FLAGS BEFORE jax initializes, and by
-# the time benchmarks/run.py reaches this table jax is already up with one
-# CPU device. The child prints one parseable SHARDED_ROW line per scene.
-_SHARDED_CHILD = r"""
-import os, sys, time
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                           + os.environ.get("XLA_FLAGS", ""))
-import numpy as np
-import jax
-import jax.numpy as jnp
-from repro.core.sar import build_pipeline
-from repro.core.sar.distributed import make_sar_mesh
-from repro.core.sar.geometry import test_scene
-
-n, iters = int(sys.argv[1]), int(sys.argv[2])
-cfg = test_scene(n)
-fn = build_pipeline(cfg, "fused1").lower_sharded(make_sar_mesh())
-rng = np.random.default_rng(0)
-raw = jnp.asarray(rng.standard_normal((cfg.na, cfg.nr))
-                  + 1j * rng.standard_normal((cfg.na, cfg.nr)),
-                  jnp.complex64)
-jax.block_until_ready(fn(raw))   # compile
-ts = []
-for _ in range(iters):
-    t0 = time.perf_counter()
-    jax.block_until_ready(fn(raw))
-    ts.append(time.perf_counter() - t0)
-ts.sort()
-res = "+".join(sorted({u["residency"] for u in fn.unit_info}))
-print(f"SHARDED_ROW {ts[len(ts) // 2]:.6f} "
-      f"devices={fn.devices};"
-      f"dispatches_per_device={fn.dispatches_per_device};"
-      f"turns={fn.turns};residency={res};scene={cfg.na}x{cfg.nr}",
-      flush=True)
-"""
-
-
 def run_sharded(full: bool = False, smoke: bool = False):
-    """table_8: fused1 lowered across 8 emulated devices — one staged
-    megakernel dispatch per device per phase group, the in-kernel corner
-    turns becoming the all_to_all collectives. --full runs the paper's
-    4096^2; the default/smoke row is a scaled 1024^2 scene (same dispatch
-    and turn counts — the architecture invariants the ratchet gates)."""
+    """table_8: fused1 lowered across every device of this process — one
+    staged megakernel dispatch per device per phase group, the in-kernel
+    corner turns becoming the all_to_all collectives. Runs in-process on
+    the real devices (a child would find the chip held by this process);
+    on a CPU host, benchmarks/run.py asks XLA for 8 host devices before
+    jax starts. --full runs the paper's 4096^2; the default/smoke row is
+    a scaled 1024^2 scene (same dispatch and turn counts)."""
+    from repro.core.sar.distributed import make_sar_mesh
     n = 4096 if full else 1024
     iters = 2 if full else 3
-    header(f"table_8: sharded fused1 {n}x{n} across 8 emulated devices "
+    p = len(jax.devices())
+    header(f"table_8: sharded fused1 {n}x{n} across {p} "
+           f"{jax.devices()[0].device_kind} devices "
            "(one megakernel dispatch per device per phase group)")
-    src = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-c", _SHARDED_CHILD, str(n), str(iters)],
-        capture_output=True, text=True, env=env,
-        timeout=3600 if full else 900)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"sharded bench child failed (rc={proc.returncode}):\n"
-            f"{proc.stdout}\n{proc.stderr}")
-    rows = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("SHARDED_ROW ")]
-    if not rows:
-        raise RuntimeError(
-            f"sharded bench child printed no SHARDED_ROW:\n{proc.stdout}")
-    for ln in rows:
-        _, secs, derived = ln.split(" ", 2)
-        emit("rda_fused1_sharded", float(secs), derived,
-             interpret=pallas_interpreted())
+    if p < 2:
+        print("# table_8 skipped: one device, nothing to shard across",
+              flush=True)
+        return
+    cfg = test_scene(n)
+    fn = build_pipeline(cfg, "fused1").lower_sharded(make_sar_mesh())
+    rng = np.random.default_rng(0)
+    raw = jnp.asarray(rng.standard_normal((cfg.na, cfg.nr))
+                      + 1j * rng.standard_normal((cfg.na, cfg.nr)),
+                      jnp.complex64)
+    t = timeit(fn, raw, warmup=1, iters=iters)
+    res = "+".join(sorted({u["residency"] for u in fn.unit_info}))
+    emit("rda_fused1_sharded", t,
+         f"devices={fn.devices};"
+         f"dispatches_per_device={fn.dispatches_per_device};"
+         f"turns={fn.turns};residency={res};scene={cfg.na}x{cfg.nr}",
+         interpret=pallas_interpreted())
 
 
 def run(n: int = 512, full: bool = False, smoke: bool = False):

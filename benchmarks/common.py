@@ -1,9 +1,8 @@
 """Benchmark utilities: timing, CSV emission, JSON recording.
 
-This container is CPU-only, so wall-clock numbers are CPU-XLA illustrative
-(Pallas kernels run in interpret mode); the TPU performance story is the
-roofline table derived from the compiled dry-run artifacts
-(EXPERIMENTS.md §Roofline). Every bench prints `name,us_per_call,derived`
+On a CPU host the Pallas kernels run in interpret mode, so wall-clock
+numbers there time the interpreter, not a kernel; every row carries that
+``interpret`` flag. Every bench prints `name,us_per_call,derived`
 rows AND records them in-process so benchmarks/run.py can write
 machine-readable BENCH_*.json artifacts (wall-ms + git SHA + backend) —
 the cross-PR perf trajectory.
@@ -66,9 +65,11 @@ def emit(name: str, seconds: float, derived: str = "",
 
 
 def pallas_interpreted() -> bool:
-    """Whether Pallas rows in this process run in interpret mode (the
-    kernels' auto_interpret default: everything off-TPU)."""
-    return jax.default_backend() != "tpu"
+    """Whether Pallas rows in this process run in interpret mode: the
+    kernels' own ``auto_interpret`` decision (interpreted on 'cpu',
+    compiled on 'tpu', an error on any other backend)."""
+    from repro.kernels.fft4step import auto_interpret
+    return auto_interpret(None)
 
 
 def header(title: str):

@@ -19,6 +19,7 @@ workflow artifacts.
 from __future__ import annotations
 
 import argparse
+import os
 
 from benchmarks import (
     bench_compare,
@@ -30,6 +31,7 @@ from benchmarks import (
 )
 from benchmarks.common import take_records, validate_bench_file, \
     write_bench_json
+from repro.runtime import use_compile_cache
 
 
 def main() -> None:
@@ -46,6 +48,12 @@ def main() -> None:
     args = ap.parse_args()
     if args.full and args.smoke:
         ap.error("--full and --smoke are mutually exclusive")
+    # table_8 shards across every device of this process; on a CPU host
+    # that is 8 host devices, which XLA must be asked for before jax
+    # starts (the flag touches only the host platform, never a TPU)
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                               + os.environ.get("XLA_FLAGS", ""))
+    use_compile_cache()
     meta = dict(full=args.full, smoke=args.smoke)
 
     print("name,us_per_call,derived")

@@ -20,11 +20,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import use_compile_cache
 from repro.core.sar import build_pipeline, metrics, paper_targets, simulate
 from repro.core.sar.geometry import test_scene
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=256)
     ap.add_argument("--batch", type=int, default=4)

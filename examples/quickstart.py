@@ -5,9 +5,12 @@
 import numpy as np
 import jax.numpy as jnp
 
+from repro.runtime import use_compile_cache
 from repro.kernels import ops, ref
 from repro.core.sar import (build_pipeline, metrics, paper_targets, simulate,
                             test_scene)
+
+use_compile_cache()
 
 # --- 1. One fused dispatch: FFT -> matched filter -> IFFT ------------------
 rng = np.random.default_rng(0)

@@ -13,12 +13,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import use_compile_cache
 from repro.core.sar import (build_pipeline, metrics, paper_targets, simulate,
                             test_scene)
 from repro.core.sar.geometry import paper_scene
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=512)
     ap.add_argument("--scenes", type=int, default=3)

@@ -11,12 +11,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import use_compile_cache
 from repro.configs import registry
 from repro.launch.serve import generate
 from repro.models import Model
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-12b")
     ap.add_argument("--batch", type=int, default=4)

@@ -18,6 +18,7 @@ import asyncio
 
 import numpy as np
 
+from repro.runtime import use_compile_cache
 from repro.core.sar import paper_targets, simulate_cached
 from repro.core.sar.geometry import test_scene
 from repro.service import (
@@ -45,7 +46,7 @@ async def main(args) -> None:
         backend=backend)
 
     print(f"warming {args.variant} for {cfg.na}x{cfg.nr} scenes ...")
-    await svc.start(warm=[(cfg, args.variant, svc.config.precision)])
+    await svc.start(warm=[(cfg, args.variant, svc.default_precision)])
 
     async def client(i: int):
         # un-annotated requests take the default serving tier (bs16:
@@ -69,7 +70,7 @@ async def main(args) -> None:
             print(f"  request {i}: dropped ({e})")
             return None
         print(f"  request {i}: focused, peak={float(np.abs(img).max()):.1f}"
-              f" precision={precision or svc.config.precision or 'f32'}"
+              f" precision={precision or svc.default_precision or 'f32'}"
               + (f" deadline_ms={deadline_ms:g}" if deadline_ms else ""))
         return img
 
@@ -108,4 +109,5 @@ if __name__ == "__main__":
                     help="device-memory budget; larger scenes stream")
     ap.add_argument("--bench-json", default=None,
                     help="write service metrics as a BENCH_*.json")
+    use_compile_cache()
     asyncio.run(main(ap.parse_args()))

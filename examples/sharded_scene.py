@@ -23,6 +23,7 @@ import time
 import numpy as np
 import jax
 
+from repro.runtime import use_compile_cache
 from repro.core.sar import (build_pipeline, metrics, paper_targets,
                             simulate_cached, test_scene)
 from repro.core.sar.distributed import make_sar_mesh
@@ -30,6 +31,7 @@ from repro.core.sar.geometry import paper_scene
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=2048)
     args = ap.parse_args()

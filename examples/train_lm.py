@@ -10,12 +10,14 @@ import tempfile
 import numpy as np
 import jax
 
+from repro.runtime import use_compile_cache
 from repro.checkpoint import CheckpointManager
 from repro.distributed import FailureInjector, run_with_restarts
 from repro.launch import train as T
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--steps", type=int, default=120)

@@ -18,7 +18,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.compat import AXIS_TYPE_AUTO, make_mesh
+from jax.sharding import AxisType
 from repro.core.sar import paper_scene
 from repro.core.sar import filters
 from repro.core.sar.distributed import build_corner2, build_halo
@@ -79,7 +79,7 @@ def main():
     # halo needs halo_cols <= nr/P: at 256 devices the slab is 16 columns ==
     # the halo itself (the exchange degenerates to a corner turn), so the
     # schedule comparison runs at 64 devices where its premise holds.
-    mesh64 = make_mesh((64,), ("data",), axis_types=(AXIS_TYPE_AUTO,))
+    mesh64 = jax.make_mesh((64,), ("data",), axis_types=(AxisType.Auto,))
     out.append(measure("corner2_64", build_corner2, mesh=mesh64))
     out.append(measure("halo_64", build_halo, mesh=mesh64))
     # iteration 3: bf16 corner-turn payload (dominant term / 2?)

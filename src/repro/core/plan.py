@@ -66,6 +66,7 @@ from repro.kernels.fft4step import (
     FILTER_OUTER,
     FILTER_SHARED,
     FILTER_SHARED_OUTER,
+    default_line_block as _line_block,
     resolve_precision,
 )
 from repro.kernels.transpose import transpose as tiled_transpose
@@ -729,7 +730,7 @@ def _make_spectral_step(group, mode, arrays, *, cfg, transposed, backend,
     if fkw:
         tuned = tuned.merge_overrides(fkw)
     if phys_axis == 1:
-        block = opts["block"] or tuned.block or 8
+        block = opts["block"] or tuned.block or _line_block()
     else:
         block = opts["col_block"] or 128
     stage_prec = next((a.stage.precision for a in group
@@ -851,7 +852,8 @@ def _make_mega_step(group, seg_payloads, *, cfg, backend, opts) -> Step:
         residency = tuning.cost.mega_residency(
             cfg.na, cfg.nr, precision=precision,
             filter_bytes=sum(int(a.size) * 4 for a in filter_args))
-    phase_block = opts["phase_block"] or tuned.phase_block or 8
+    phase_block = (opts["phase_block"] or tuned.phase_block
+                   or _line_block())
 
     # per-segment schedule decisions ride as extended 8-field segment
     # records (axis, fwd, inv, mode, n1, n2, n3, karatsuba) — the kernel
@@ -987,7 +989,8 @@ def compile_plan(
     residency: megakernel execution mode for mega-fused steps — 'vmem'
       (whole slab on-chip) or 'staged' (HBM scratch + double-buffered
       DMA); None auto-selects by the repro.tuning VMEM-feasibility cut.
-    phase_block: lines per staged-phase grid step (None = tuned or 8).
+    phase_block: lines per staged-phase grid step (None = tuned, else
+      the device's line block, repro.tuning.cost.DeviceSpec.line_block).
     batch: scene-batch size the tuned configs are *looked up* for
       (normalized to the serving power-of-two bucket by repro.tuning);
       it does not restrict the shapes the pipeline accepts.

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.sar import filters
 from repro.kernels.fft4step import (
     FILTER_FULL,
@@ -52,6 +51,7 @@ from repro.kernels.fft4step import (
     FILTER_OUTER,
     FILTER_SHARED,
     FILTER_SHARED_OUTER,
+    default_line_block as _line_block,
     resolve_precision,
 )
 from repro.core.sar.geometry import SceneConfig
@@ -149,7 +149,7 @@ def build_corner2(cfg: SceneConfig, mesh: Mesh, axes=("data",),
         return xr, xi
 
     shard = functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, axes), P(None, axes), P(axes), P(axes, None)),
         out_specs=(P(None, axes), P(None, axes)), check_vma=False)
 
@@ -245,7 +245,7 @@ def build_halo(cfg: SceneConfig, mesh: Mesh, axes=("data",),
         return xr, xi
 
     shard = functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), P(axes)),
         out_specs=(P(None, axes), P(None, axes)), check_vma=False)
 
@@ -316,7 +316,7 @@ def _lowerable_steps(pipe) -> list:
 def _clamped_block(kernel_kw: dict, lines_local: int) -> dict:
     """The per-dispatch line block must fit (and divide) the local slab."""
     kw = dict(kernel_kw)
-    blk = min(int(kw.get("block") or 8), lines_local)
+    blk = min(int(kw.get("block") or _line_block()), lines_local)
     while lines_local % blk:
         blk -= 1
     kw["block"] = max(1, blk)
@@ -409,7 +409,7 @@ def _group_mega_kw(src: dict, recs, stream_axis: int, lines_local: int,
         # factor the transform axis, which sharding never slices).
         for k in ("n1", "n2", "n3"):
             kw[k] = src.get(k)
-    kw["phase_block"] = _divisor_block(src.get("phase_block") or 8,
+    kw["phase_block"] = _divisor_block(src.get("phase_block") or _line_block(),
                                        lines_local)
     if residency is None:
         from repro import tuning
@@ -592,7 +592,7 @@ def lower_pipeline(pipe, mesh: Mesh, axes=("data",), turn_dtype=None,
             return xr, xi
 
         shard = functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(dspec(units[0][0]), dspec(units[0][0]), *farg_specs),
             out_specs=(dspec(units[-1][0]), dspec(units[-1][0])),
             check_vma=False)

@@ -8,8 +8,8 @@ of point targets under the hyperbolic range equation
 with a linear-FM transmitted chirp and rectangular range/azimuth windows, plus
 additive circular Gaussian noise at the configured raw SNR (paper: 20 dB).
 
-Pure jnp; vectorized over the full (na, nr) grid per target so the simulator
-itself runs on-device and is jit-able.
+Pure jnp, vectorized over the full (na, nr) grid per target, on the host
+CPU device (see :func:`simulate`).
 """
 from __future__ import annotations
 
@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import enable_x64
 from repro.core.sar.geometry import C, PointTarget, SceneConfig
 
 
@@ -51,31 +50,38 @@ def _target_echo(cfg: SceneConfig, eta, t, tgt: PointTarget) -> jnp.ndarray:
 
 
 def simulate(cfg: SceneConfig, targets: list[PointTarget],
-             add_noise: bool = True) -> jnp.ndarray:
-    """Raw echo matrix (na, nr) complex64 for all targets (+ noise)."""
+             add_noise: bool = True) -> np.ndarray:
+    """Raw echo matrix (na, nr) complex64 for all targets (+ noise).
+
+    Runs on the host CPU device whatever the default backend: the float64
+    phase math has no TPU lowering, and making the input is set-up, not
+    the focusing path. Returns a host array, so the caller's first jitted
+    use places it on the caller's device."""
     cfg.validate()
-    with enable_x64(True):
-        eta, t = time_axes(cfg)
-        acc = jnp.zeros((cfg.na, cfg.nr), jnp.complex64)
-        for tgt in targets:
-            acc = acc + _target_echo(cfg, eta, t, tgt)
-    if add_noise and cfg.noise_db is not None:
-        # raw per-sample echo power within the support is sigma^2; scale noise
-        # for the configured raw SNR
-        snr_lin = 10.0 ** (cfg.noise_db / 10.0)
-        sigma_n = float(np.sqrt(1.0 / (2.0 * snr_lin)))
-        key = jax.random.PRNGKey(cfg.seed)
-        k1, k2 = jax.random.split(key)
-        noise = (jax.random.normal(k1, acc.shape, jnp.float32) +
-                 1j * jax.random.normal(k2, acc.shape, jnp.float32)) * sigma_n
-        acc = acc + noise.astype(jnp.complex64)
-    return acc
+    with jax.default_device(jax.local_devices(backend="cpu")[0]):
+        with jax.enable_x64(True):
+            eta, t = time_axes(cfg)
+            acc = jnp.zeros((cfg.na, cfg.nr), jnp.complex64)
+            for tgt in targets:
+                acc = acc + _target_echo(cfg, eta, t, tgt)
+        if add_noise and cfg.noise_db is not None:
+            # raw per-sample echo power within the support is sigma^2;
+            # scale noise for the configured raw SNR
+            snr_lin = 10.0 ** (cfg.noise_db / 10.0)
+            sigma_n = float(np.sqrt(1.0 / (2.0 * snr_lin)))
+            key = jax.random.PRNGKey(cfg.seed)
+            k1, k2 = jax.random.split(key)
+            noise = (jax.random.normal(k1, acc.shape, jnp.float32) +
+                     1j * jax.random.normal(k2, acc.shape, jnp.float32)
+                     ) * sigma_n
+            acc = acc + noise.astype(jnp.complex64)
+        return np.asarray(acc)
 
 
 @functools.lru_cache(maxsize=4)
 def _cached_scene_np(cfg: SceneConfig, targets: tuple[PointTarget, ...],
                      add_noise: bool) -> np.ndarray:
-    return np.asarray(simulate(cfg, list(targets), add_noise))
+    return simulate(cfg, list(targets), add_noise)
 
 
 def simulate_cached(cfg: SceneConfig, targets: list[PointTarget],

@@ -5,7 +5,8 @@ holding a 4096-point complex line in 32 KiB of threadgroup memory, and feeds
 Apple's 8x8 simdgroup MMA with a radix-8 DFT butterfly.
 
 TPU adaptation (see DESIGN.md SS2):
-  * on-chip tier   : 32 KiB threadgroup memory  ->  ~16 MiB VMEM. We block a
+  * on-chip tier   : 32 KiB threadgroup memory  ->  VMEM (128 MiB on v5e,
+    limits sized per kernel by :func:`compiler_params`). We block a
     *batch of lines* (row pipeline) or a whole (N x L) column slab (column
     pipeline) per grid step, instead of one line per threadgroup.
   * matrix unit    : 8x8 simdgroup MMA -> 128x128 MXU. The radix-8 butterfly
@@ -92,12 +93,74 @@ RESIDENT_STAGED = "staged"  # phase-split grid + HBM scratch, DMA-staged
 
 
 def auto_interpret(interpret: Optional[bool]) -> bool:
-    """Resolve the tri-state ``interpret`` flag every kernel wrapper takes:
-    None auto-selects interpret mode off-TPU (this container is CPU-only;
-    on a real TPU fleet the same code lowers to Mosaic)."""
+    """Resolve the tri-state ``interpret`` flag every kernel wrapper takes.
+
+    None compiles the kernels with Mosaic on a TPU and runs them in the
+    Pallas interpreter on the host CPU (the test path). Any other backend
+    raises: a GPU or plugin backend must never quietly fall back to the
+    interpreter and report its times as kernel times."""
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"no Pallas lowering for backend {backend!r}: kernels compile for "
+        "'tpu' and are interpreted only on 'cpu'")
+
+
+def device_kind() -> str:
+    """``device_kind`` of the first JAX device — the key of the per-device
+    table in ``repro.tuning.cost`` that sizes VMEM limits and line blocks."""
+    return jax.devices()[0].device_kind
+
+
+def _device_spec():
+    from repro.tuning.cost import device_spec  # tuning imports this module
+    return device_spec(device_kind())
+
+
+def default_line_block() -> int:
+    """Lines per rows-dispatch grid step and per staged-megakernel phase
+    step when none is pinned: the device table's ``line_block``."""
+    return _device_spec().line_block
+
+
+def check_precision(precision, interpret: bool) -> None:
+    """Refuse a compiled kernel whose matmul operand dtype the device's
+    matrix unit does not take (float16 on TPU v5e: Mosaic cannot even
+    pack f32 into f16 there). Interpret mode emulates every dtype."""
+    prec = resolve_precision(precision)
+    if interpret or prec.dtype != "float16":
+        return
+    spec = _device_spec()
+    if not spec.f16_operands:
+        raise ValueError(
+            f"precision {prec.name!r} needs float16 matmul operands, which "
+            f"{spec.kind} does not support; use one of "
+            f"{sorted(p for p, v in PRECISIONS.items() if v.dtype != 'float16')}")
+
+
+_DEFAULT_SCOPED_VMEM = 16 * 2**20     # Mosaic's limit when none is set
+
+
+def compiler_params(vmem_estimate: int, interpret: bool, **kw):
+    """Mosaic compiler parameters with a scoped-VMEM limit sized from the
+    kernel's footprint estimate: 25% headroom over it, never below the
+    compiler's own default and never above what the device has. A kernel
+    whose estimate alone exceeds the device raises here, not in Mosaic."""
+    if interpret:
+        return None
+    spec = _device_spec()
+    if vmem_estimate > spec.vmem_bytes:
+        raise ValueError(
+            f"kernel needs ~{vmem_estimate / 2**20:.1f} MiB of VMEM; "
+            f"{spec.kind} has {spec.vmem_bytes / 2**20:.0f} MiB")
+    limit = min(spec.vmem_bytes,
+                max(_DEFAULT_SCOPED_VMEM, int(vmem_estimate * 1.25)))
+    return pltpu.CompilerParams(vmem_limit_bytes=limit, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -294,12 +357,16 @@ def _cast(x, precision: str):
 def _cdot(fr, fi, xr, xi, dims, *, karatsuba: bool, precision: str):
     """Complex dot_general: (fr + i fi) . (xr + i xi) with contraction `dims`.
 
-    4 real matmuls, or 3 with Karatsuba (P3 = (Fr+Fi)(Xr+Xi)). f32 accumulate.
+    4 real matmuls, or 3 with Karatsuba (P3 = (Fr+Fi)(Xr+Xi)). f32
+    accumulate; f32 operands are multiplied at full precision (a TPU's
+    default f32 matmul would round them to bf16 first).
     """
     dg = functools.partial(
         jax.lax.dot_general,
         dimension_numbers=(dims, ((), ())),
         preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST
+                   if PRECISIONS[precision].dtype == "float32" else None),
     )
     fr_, fi_ = _cast(fr, precision), _cast(fi, precision)
     xr_, xi_ = _cast(xr, precision), _cast(xi, precision)
@@ -313,28 +380,16 @@ def _cdot(fr, fi, xr, xi, dims, *, karatsuba: bool, precision: str):
     return yr, yi
 
 
-def _cdot_rhs(xr, xi, fr, fi, dims, *, karatsuba: bool, precision: str):
-    """Complex dot_general with the DFT matrix on the right: X . F."""
-    dg = functools.partial(
-        jax.lax.dot_general,
-        dimension_numbers=(dims, ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    fr_, fi_ = _cast(fr, precision), _cast(fi, precision)
-    xr_, xi_ = _cast(xr, precision), _cast(xi, precision)
-    if karatsuba:
-        p1 = dg(xr_, fr_)
-        p2 = dg(xi_, fi_)
-        p3 = dg(_cast(xr + xi, precision), _cast(fr + fi, precision))
-        return p1 - p2, p3 - p1 - p2
-    yr = dg(xr_, fr_) - dg(xi_, fi_)
-    yi = dg(xi_, fr_) + dg(xr_, fi_)
-    return yr, yi
-
-
 # ---------------------------------------------------------------------------
-# Four-step matmul FFT, in-kernel (rows: transform the last axis of (L, N))
+# Four-step matmul FFT, in-kernel: lines ride the lane dimension
 # ---------------------------------------------------------------------------
+#
+# Every transform runs on an (N, C) slab whose C lines sit in the 128-wide
+# lane dimension and whose transform axis N splits only along sublanes and
+# leading dims. Mosaic cannot split a lane dimension into 64-wide pieces
+# (the (L, 4096) -> (L, 64, 64) reshape of a row-major four-step), so a
+# rows dispatch transposes its (L, N) block to (N, L) in VMEM, runs the
+# same column recursion, and transposes back.
 
 def _split_consts(consts, factors):
     """(per-stage DFT matrix pairs, per-boundary twiddle pairs)."""
@@ -345,72 +400,40 @@ def _split_consts(consts, factors):
     return mats, tws
 
 
-def _fft_rows_matmul(xr, xi, consts, spec: SpectralSpec):
-    """Mixed-radix four-step FFT along the last axis of (L, N).
+def _fft_cols_matmul(xr, xi, consts, spec: SpectralSpec):
+    """Mixed-radix four-step FFT along axis 0 of an (N, C) column slab.
 
-    Recursive Cooley-Tukey over spec.factors(): at stage i the length-m
-    block (m = prod of the remaining factors) is reshaped to (f_i, m/f_i),
-    contracted with the f_i-point DFT matrix on the MXU, twiddled, and the
-    remainder transformed recursively. Two factors reproduce the classic
-    four-step (stage A matmul, twiddle, stage C matmul) exactly.
+    Recursive Cooley-Tukey over spec.factors(): at stage i the (m, *batch)
+    slab (m = prod of the remaining factors) splits its leading axis to
+    (f_i, m/f_i, *batch), is contracted with the f_i-point DFT matrix on
+    the MXU, twiddled, and the (m/f_i) remainder is transformed with the
+    stage-i output index riding along as one more batch dim. The split,
+    the swap of the two leading dims and the final merge never touch the
+    lane dimension.
     """
     factors = spec.factors()
     mats, tws = _split_consts(consts, factors)
     kw = dict(karatsuba=spec.karatsuba, precision=spec.precision)
 
     def rec(xr, xi, i):
-        # xr/xi: (M, m) — transform the last axis, m = prod(factors[i:])
-        M, m = xr.shape
-        f = factors[i]
-        fr, fi = mats[i]
-        if i == len(factors) - 1:
-            # base: one dense DFT matmul (DFT matrices are symmetric)
-            return _cdot_rhs(xr, xi, fr, fi, ((1,), (0,)), **kw)
-        rest = m // f
-        x3r = xr.reshape(M, f, rest)
-        x3i = xi.reshape(M, f, rest)
-        # stage A: contract f with F_i -> (f, M, rest), index k_i first
-        ar, ai = _cdot(fr, fi, x3r, x3i, ((1,), (1,)), **kw)
-        twr, twi = tws[i]
-        br, bi = _cmul(ar, ai, twr[:, None, :], twi[:, None, :])
-        # recurse on the remaining length
-        zr, zi = rec(br.reshape(f * M, rest), bi.reshape(f * M, rest), i + 1)
-        zr = zr.reshape(f, M, rest)
-        zi = zi.reshape(f, M, rest)
-        # out[l, k_rest * f + k_i] = z[k_i, l, k_rest]
-        return (jnp.transpose(zr, (1, 2, 0)).reshape(M, m),
-                jnp.transpose(zi, (1, 2, 0)).reshape(M, m))
-
-    return rec(xr, xi, 0)
-
-
-def _fft_cols_matmul(xr, xi, consts, spec: SpectralSpec):
-    """Mixed-radix four-step FFT along axis 0 of an (N, C) column slab —
-    no global transpose needed (same recursion as rows, column layout)."""
-    factors = spec.factors()
-    mats, tws = _split_consts(consts, factors)
-    kw = dict(karatsuba=spec.karatsuba, precision=spec.precision)
-
-    def rec(xr, xi, i):
-        # xr/xi: (m, C) — transform axis 0, m = prod(factors[i:])
-        m, C = xr.shape
+        # xr/xi: (m, *batch) — transform axis 0, m = prod(factors[i:])
+        m, batch = xr.shape[0], xr.shape[1:]
         f = factors[i]
         fr, fi = mats[i]
         if i == len(factors) - 1:
             return _cdot(fr, fi, xr, xi, ((1,), (0,)), **kw)
         rest = m // f
-        x3r = xr.reshape(f, rest, C)
-        x3i = xi.reshape(f, rest, C)
-        # stage A: contract f with F_i -> (f, rest, C)
+        x3r = xr.reshape(f, rest, *batch)
+        x3i = xi.reshape(f, rest, *batch)
+        # stage A: contract f with F_i -> (f, rest, *batch)
         ar, ai = _cdot(fr, fi, x3r, x3i, ((1,), (0,)), **kw)
         twr, twi = tws[i]
-        br, bi = _cmul(ar, ai, twr[:, :, None], twi[:, :, None])
-        # recurse along the remaining length: (rest, f*C)
-        cr = jnp.transpose(br, (1, 0, 2)).reshape(rest, f * C)
-        ci = jnp.transpose(bi, (1, 0, 2)).reshape(rest, f * C)
-        zr, zi = rec(cr, ci, i + 1)
-        # out[k_rest * f + k_i, c] = z[k_rest, k_i, c] — a plain reshape
-        return zr.reshape(m, C), zi.reshape(m, C)
+        tshape = (f, rest) + (1,) * len(batch)
+        br, bi = _cmul(ar, ai, twr.reshape(tshape), twi.reshape(tshape))
+        # recurse along the remaining length: (rest, f, *batch)
+        zr, zi = rec(jnp.swapaxes(br, 0, 1), jnp.swapaxes(bi, 0, 1), i + 1)
+        # out[k_rest * f + k_i] = z[k_rest, k_i] — a leading-dim merge
+        return zr.reshape(m, *batch), zi.reshape(m, *batch)
 
     return rec(xr, xi, 0)
 
@@ -431,7 +454,7 @@ def _fft_stockham(xr, xi, spec: SpectralSpec, axis: int):
     while n > 1:
         if n % 4 == 0:
             m = n // 4
-            k = jax.lax.broadcasted_iota(jnp.float32, (m, 1), 0)
+            k = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0).astype(jnp.float32)
             th = (-2.0 * math.pi / n) * k
             w1r, w1i = jnp.cos(th), jnp.sin(th)
             w2r, w2i = _cmul(w1r, w1i, w1r, w1i)
@@ -459,7 +482,7 @@ def _fft_stockham(xr, xi, spec: SpectralSpec, axis: int):
             n, s = m, 4 * s
         else:
             m = n // 2
-            k = jax.lax.broadcasted_iota(jnp.float32, (m, 1), 0)
+            k = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0).astype(jnp.float32)
             th = (-2.0 * math.pi / n) * k
             wr, wi = jnp.cos(th), jnp.sin(th)
             a_r, a_i = yr[:, :m, :], yi[:, :m, :]
@@ -478,38 +501,34 @@ def _fft_stockham(xr, xi, spec: SpectralSpec, axis: int):
 def _run_fft(xr, xi, consts, spec: SpectralSpec, inverse: bool):
     """Forward or inverse (conj-FFT-conj) transform along spec.axis.
 
-    x is a (Bb, L, n) / (Bb, n, L) batch block: the batch dim folds into
-    the line dim for the transform (scenes are independent lines), so one
-    grid step's matmuls span Bb * L lines — THE amortization: DFT constants
-    are loaded once per step and shared by every scene in the block.
+    x is a (Bb, L, n) / (Bb, n, L) batch block. Each scene of the block
+    runs through the same per-scene code — one grid step loads the DFT
+    constants once for all of them, and a scene's lines see identical
+    matmul shapes whatever the batch block, so batched images equal
+    unbatched ones. Rows blocks transpose to the (n, L) column layout and
+    back (see ``_fft_cols_matmul``).
     """
-    bb = xr.shape[0]
-    if spec.axis == 1:
-        # (Bb, L, n) -> (Bb*L, n): contiguous, a free reshape
-        xr2 = xr.reshape(bb * xr.shape[1], xr.shape[2])
-        xi2 = xi.reshape(bb * xi.shape[1], xi.shape[2])
-    else:
-        # (Bb, n, L) -> (n, Bb*L): the scene axis must stay leading
-        xr2 = jnp.moveaxis(xr, 0, 1).reshape(xr.shape[1], bb * xr.shape[2])
-        xi2 = jnp.moveaxis(xi, 0, 1).reshape(xi.shape[1], bb * xi.shape[2])
-    if inverse:
-        xi2 = -xi2
-    if spec.fft_impl == "matmul":
-        fft = _fft_rows_matmul if spec.axis == 1 else _fft_cols_matmul
-        yr, yi = fft(xr2, xi2, consts, spec)
-    elif spec.fft_impl == "stockham":
-        yr, yi = _fft_stockham(xr2, xi2, spec, spec.axis)
-    else:
+    if spec.fft_impl not in ("matmul", "stockham"):
         raise ValueError(f"unknown fft_impl {spec.fft_impl}")
-    if inverse:
-        # conj + 1/N, folded into the final store (paper SSII-C)
-        scale = 1.0 / spec.n
-        yr, yi = yr * scale, yi * (-scale)
-    if spec.axis == 1:
-        return yr.reshape(xr.shape), yi.reshape(xi.shape)
-    yr = jnp.moveaxis(yr.reshape(xr.shape[1], bb, xr.shape[2]), 1, 0)
-    yi = jnp.moveaxis(yi.reshape(xi.shape[1], bb, xi.shape[2]), 1, 0)
-    return yr, yi
+    outs_r, outs_i = [], []
+    for b in range(xr.shape[0]):
+        yr, yi = xr[b], xi[b]
+        if inverse:
+            yi = -yi
+        if spec.fft_impl == "stockham":
+            yr, yi = _fft_stockham(yr, yi, spec, spec.axis)
+        elif spec.axis == 1:
+            yr, yi = _fft_cols_matmul(yr.T, yi.T, consts, spec)
+            yr, yi = yr.T, yi.T
+        else:
+            yr, yi = _fft_cols_matmul(yr, yi, consts, spec)
+        if inverse:
+            # conj + 1/N, folded into the final store (paper SSII-C)
+            scale = 1.0 / spec.n
+            yr, yi = yr * scale, yi * (-scale)
+        outs_r.append(yr)
+        outs_i.append(yi)
+    return jnp.stack(outs_r), jnp.stack(outs_i)
 
 
 def _filter_ref_count(filter_mode: str) -> int:
@@ -527,15 +546,15 @@ def _apply_filters(xr, xi, axis: int, filter_mode: str, filt):
         u = u_ref[...]      # rows: (L, K); cols: (K, C)  — per-line parameters
         v = v_ref[...]      # rows: (K, N); cols: (N, K)  — per-sample parameters
         # rank-K phase synthesized in VMEM (no 2-D filter I/O); the 2-D
-        # phase broadcasts across the leading batch-block dim
-        if axis == 1:
-            phase = jax.lax.dot_general(
-                u, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            phase = jax.lax.dot_general(
-                v, u, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        # phase broadcasts across the leading batch-block dim. K is 1-2:
+        # a sum of K outer products on the VPU keeps the phase exact f32,
+        # where a TPU matmul's default pass rounds u and v to bf16 (the
+        # azimuth phase of a 4096² scene reaches ~480 rad, so bf16
+        # operands put radians of error into it)
+        a, b = (u, v) if axis == 1 else (v, u)
+        phase = a[:, 0:1] * b[0:1, :]
+        for k in range(1, a.shape[1]):
+            phase = phase + a[:, k:k + 1] * b[k:k + 1, :]
         return _cmul(xr, xi, jnp.cos(phase), jnp.sin(phase))
 
     if filter_mode in (FILTER_SHARED, FILTER_FULL):
@@ -654,6 +673,69 @@ def _flops_per_line(spec: SpectralSpec) -> float:
     return f
 
 
+def tile_bytes(shape, itemsize: int = 4) -> int:
+    """VMEM bytes of one array laid out in (8, 128) tiles: the last dim
+    pads to a multiple of 128 lanes, the one before it to 8 sublanes."""
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    *lead, r, c = shape
+    return (math.prod(lead) * (-(-r // 8) * 8) * (-(-c // 128) * 128)
+            * itemsize)
+
+
+# Live (n, L) f32 temporaries one scene's transform keeps in VMEM at its
+# peak (re/im of the loaded block, the transposed copy, the four real
+# products of a complex matmul, the twiddled and swapped stage outputs),
+# fitted so the estimate bounds what Mosaic allocates for the v5e
+# compiles in tests/test_tpu_compile.py.
+_TEMP_SLABS = 16
+
+
+def _filter_block_shapes(filter_mode: str, axis: int, n: int, L: int,
+                         K: int) -> list:
+    if axis == 1:
+        shared, full, u, v = (1, n), (L, n), (L, K), (K, n)
+    else:
+        shared, full, u, v = (n, 1), (n, L), (K, L), (n, K)
+    return {FILTER_NONE: [], FILTER_SHARED: [shared, shared],
+            FILTER_FULL: [full, full], FILTER_OUTER: [u, v],
+            FILTER_SHARED_OUTER: [shared, shared, u, v]}[filter_mode]
+
+
+def _const_shapes(spec: SpectralSpec) -> list:
+    if spec.fft_impl != "matmul" or not (spec.fwd or spec.inv):
+        return []
+    return [c.shape for c in dft_constants(*spec.factors())]
+
+
+def spectral_vmem_bytes(spec: SpectralSpec, batch_block: int = 1) -> int:
+    """Estimated VMEM of one grid step of a per-axis dispatch: the x and
+    y blocks double-buffered by the Pallas pipeline, the constant and
+    filter blocks (also double-buffered), and the transform's in-kernel
+    temporaries. ``compiler_params`` sizes the Mosaic limit from it and
+    the tuner's feasibility cut (``repro.tuning.cost``) reads it."""
+    n, L = spec.n, spec.block
+    blk = tile_bytes((batch_block, L, n) if spec.axis == 1
+                     else (batch_block, n, L))
+    scene = tile_bytes((n, L))
+    total = 8 * blk + 4 * scene * batch_block + _TEMP_SLABS * scene
+    total += 2 * sum(tile_bytes(s) for s in _const_shapes(spec))
+    total += 2 * sum(tile_bytes(s) for s in _filter_block_shapes(
+        spec.filter_mode, spec.axis, n, L, spec.outer_rank))
+    return total
+
+
+def default_batch_block(spec: SpectralSpec, batch: int) -> int:
+    """Scenes per grid step when none is pinned: the whole batch if its
+    block fits the device's per-step VMEM budget, else the largest
+    divisor of ``batch`` that does (at least 1)."""
+    budget = _device_spec().vmem_budget_bytes
+    for bb in range(batch, 0, -1):
+        if batch % bb == 0 and (
+                bb == 1 or spectral_vmem_bytes(spec, bb) <= budget):
+            return bb
+    return 1
+
+
 def build_spectral_call(spec: SpectralSpec, lines: int, batch: int = 1,
                         interpret: bool = False, config=None):
     """Returns fn(xr, xi, *filter_args) -> (yr, yi) as a single pallas_call.
@@ -668,17 +750,19 @@ def build_spectral_call(spec: SpectralSpec, lines: int, batch: int = 1,
     The grid runs over (batch-blocks, line-blocks) with each grid step
     holding a (Bb, L, N) slab — the same line-block of Bb scenes at once —
     so the DFT-constant loads and the per-step dispatch overhead amortize
-    across the batch (spec.batch_block defaults to the whole batch; cap it
-    when Bb * L * N would overflow VMEM). Filters are 2-D and batch-shared
+    across the batch (spec.batch_block defaults to the largest divisor of
+    the batch whose block fits the device's VMEM budget,
+    :func:`default_batch_block`). Filters are 2-D and batch-shared
     (every scene uses the same SceneConfig filters).
     """
     if config is not None:
         spec = config.apply(spec)
+    check_precision(spec.precision, interpret)
     n = spec.n
     L = spec.block
     if lines % L:
         raise ValueError(f"lines={lines} not divisible by block={L}")
-    Bb = spec.batch_block or batch
+    Bb = spec.batch_block or default_batch_block(spec, batch)
     if batch % Bb:
         raise ValueError(f"batch={batch} not divisible by batch_block={Bb}")
     grid = (batch // Bb, lines // L)
@@ -731,7 +815,10 @@ def build_spectral_call(spec: SpectralSpec, lines: int, batch: int = 1,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=compiler_params(spectral_vmem_bytes(spec, Bb),
+                                        interpret),
         interpret=interpret,
+        name=f"spectral_axis{spec.axis}",
     )
 
     def fn(xr, xi, *filter_args):
@@ -758,7 +845,8 @@ def build_spectral_call(spec: SpectralSpec, lines: int, batch: int = 1,
 #                 grid step; a "turn" is purely logical (the cols transform
 #                 contracts axis 0 of the same slab — no data movement).
 #                 Zero HBM intermediates: the paper's claim realized on TPU,
-#                 for scenes whose slab fits the ~16 MiB budget. The TPU
+#                 for scenes whose slab fits the device's VMEM budget
+#                 (repro.tuning.cost.DEVICES). The TPU
 #                 analogue of the Radix-8 Stockham two-tier register/
 #                 threadgroup decomposition (arXiv 2603.27569) — VMEM plays
 #                 the register tier.
@@ -1004,9 +1092,10 @@ def _mega_kernel_staged(spec: MegaSpec, *refs):
 
     Ref order: xr, xi (ANY), [per-axis DFT constants (VMEM)],
     [per-segment filters: FULL pairs in ANY (DMA-sliced with the line
-    block), everything else resident in VMEM], or, oi (ANY), then
-    scratch: sr, si (ANY — the HBM corner-turn intermediate), the
-    double-buffered VMEM line slabs (rows and/or cols orientation, plus
+    block), everything else resident in VMEM], the outputs or, oi and
+    sr, si (ANY — sr/si is the HBM corner-turn intermediate, an output
+    only because Mosaic allocates scratch in VMEM/SMEM alone; the wrapper
+    drops it), then scratch: the double-buffered VMEM line slabs (rows and/or cols orientation, plus
     FULL-filter slabs where needed), the bs16 per-line exponent-state
     vectors er (na, 1) / ec (1, nr) when the precision is block-scaled,
     and the DMA semaphores (2 slots x 6
@@ -1222,6 +1311,7 @@ def build_mega_call(spec: MegaSpec, batch: int = 1,
       double-buffered DMA against an HBM scratch intermediate (see
       :func:`_mega_kernel_staged`).
     """
+    check_precision(spec.precision, interpret)
     na, nr = spec.na, spec.nr
     const_plan = _mega_const_plan(spec)
     const_arrays = [jnp.asarray(c) for _, cs in const_plan for c in cs]
@@ -1249,11 +1339,14 @@ def build_mega_call(spec: MegaSpec, batch: int = 1,
             in_specs=in_specs,
             out_specs=[x_spec, x_spec],
             out_shape=out_shape,
+            compiler_params=compiler_params(mega_vmem_bytes(spec, bb),
+                                            interpret),
             interpret=interpret,
+            name="mega_vmem",
         )
     else:
         phases, steps = _staged_phases(spec)
-        any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
         in_specs = [any_spec, any_spec]
         in_specs += [pl.BlockSpec(c.shape, lambda b, s: (0, 0))
                      for c in const_arrays]
@@ -1263,40 +1356,85 @@ def build_mega_call(spec: MegaSpec, batch: int = 1,
             else:
                 in_specs += [pl.BlockSpec(shape, lambda b, s: (0, 0))
                              for shape in _seg_filter_shapes(spec, seg)]
-        pb_r = next((p["pb"] for p in phases if p["axis"] == 1), None)
-        pb_c = next((p["pb"] for p in phases if p["axis"] == 0), None)
-        depth = spec.buffer_depth
-        scratch = [pltpu.ANY((na, nr), jnp.float32),
-                   pltpu.ANY((na, nr), jnp.float32)]
-        if pb_r is not None:
-            scratch.append(pltpu.VMEM((depth, 2, pb_r, nr), jnp.float32))
-        if pb_c is not None:
-            scratch.append(pltpu.VMEM((depth, 2, na, pb_c), jnp.float32))
-        if any(p["axis"] == 1 and p["seg"].filter_mode == FILTER_FULL
-               for p in phases):
-            scratch.append(pltpu.VMEM((depth, 2, pb_r, nr), jnp.float32))
-        if any(p["axis"] == 0 and p["seg"].filter_mode == FILTER_FULL
-               for p in phases):
-            scratch.append(pltpu.VMEM((depth, 2, na, pb_c), jnp.float32))
-        if PRECISIONS[spec.precision].block_scaled:
-            # bs16 carried-exponent state: per-row and per-col exponent
-            # vectors persisting across the sequential phase steps, so
-            # the HBM scratch stays scaled end to end (_mega_kernel_staged)
-            scratch.append(pltpu.VMEM((na, 1), jnp.float32))
-            scratch.append(pltpu.VMEM((1, nr), jnp.float32))
-        scratch.append(pltpu.SemaphoreType.DMA((depth, 6)))
+        # the corner-turn intermediate lives in HBM; Mosaic allocates
+        # scratch only in VMEM/SMEM, so it rides as two extra outputs
+        # that the wrapper drops
+        out_shape += [jax.ShapeDtypeStruct((na, nr), jnp.float32)] * 2
         call = pl.pallas_call(
             functools.partial(_mega_kernel_staged, spec),
             grid=(batch, steps),
             in_specs=in_specs,
-            out_specs=[any_spec, any_spec],
+            out_specs=[any_spec] * 4,
             out_shape=out_shape,
-            scratch_shapes=scratch,
+            scratch_shapes=_staged_scratch(spec, phases),
+            compiler_params=compiler_params(
+                mega_vmem_bytes(spec), interpret,
+                dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
+            name="mega_staged",
         )
 
     def fn(xr, xi, *filter_args):
-        return call(xr, xi, *const_arrays, *filter_args)
+        yr, yi = call(xr, xi, *const_arrays, *filter_args)[:2]
+        return yr, yi
 
     fn.flops = _mega_flops(spec) * batch  # nominal, for benches
     return fn
+
+
+def _staged_scratch(spec: MegaSpec, phases: list) -> list:
+    """VMEM scratch of the staged megakernel, in kernel ref order: the
+    row and/or column line slabs, FULL-filter slabs where a phase needs
+    them, the bs16 exponent state, and the DMA semaphores."""
+    na, nr, depth = spec.na, spec.nr, spec.buffer_depth
+    pb_r = next((p["pb"] for p in phases if p["axis"] == 1), None)
+    pb_c = next((p["pb"] for p in phases if p["axis"] == 0), None)
+    scratch = []
+    if pb_r is not None:
+        scratch.append(pltpu.VMEM((depth, 2, pb_r, nr), jnp.float32))
+    if pb_c is not None:
+        scratch.append(pltpu.VMEM((depth, 2, na, pb_c), jnp.float32))
+    if any(p["axis"] == 1 and p["seg"].filter_mode == FILTER_FULL
+           for p in phases):
+        scratch.append(pltpu.VMEM((depth, 2, pb_r, nr), jnp.float32))
+    if any(p["axis"] == 0 and p["seg"].filter_mode == FILTER_FULL
+           for p in phases):
+        scratch.append(pltpu.VMEM((depth, 2, na, pb_c), jnp.float32))
+    if PRECISIONS[spec.precision].block_scaled:
+        # bs16 carried-exponent state: per-row and per-col exponent
+        # vectors persisting across the sequential phase steps, so
+        # the HBM scratch stays scaled end to end (_mega_kernel_staged)
+        scratch.append(pltpu.VMEM((na, 1), jnp.float32))
+        scratch.append(pltpu.VMEM((1, nr), jnp.float32))
+    scratch.append(pltpu.SemaphoreType.DMA((depth, 6)))
+    return scratch
+
+
+def mega_vmem_bytes(spec: MegaSpec, batch_block: int = 1,
+                    devices: int = 1) -> int:
+    """Estimated VMEM of one megakernel grid step (same accounting as
+    :func:`spectral_vmem_bytes`). VMEM-resident: the whole (Bb, na, nr)
+    slab double-buffered in and out plus whole-scene temporaries — 1/P
+    of its lines per device when sharded over ``devices``. Staged: the
+    line-slab scratch plus one phase block's temporaries."""
+    na, nr = spec.na, spec.nr
+    total = 2 * sum(tile_bytes(c.shape) for _, cs in _mega_const_plan(spec)
+                    for c in cs)
+    if spec.residency == RESIDENT_VMEM:
+        scene = tile_bytes((max(1, na // devices), nr))
+        total += 8 * batch_block * scene + 4 * batch_block * scene
+        total += _TEMP_SLABS * scene
+        total += 2 * sum(tile_bytes(s) for seg in spec.segments
+                         for s in _seg_filter_shapes(spec, seg))
+        return total
+    phases, _ = _staged_phases(spec)
+    for seg in spec.segments:
+        if seg.filter_mode != FILTER_FULL:
+            total += 2 * sum(tile_bytes(s)
+                             for s in _seg_filter_shapes(spec, seg))
+    for buf in _staged_scratch(spec, phases):
+        if buf.memory_space == pltpu.VMEM:
+            total += tile_bytes(buf.shape)
+    total += max((_TEMP_SLABS + 4) * tile_bytes(
+        (p["pb"], nr) if p["axis"] == 1 else (na, p["pb"])) for p in phases)
+    return total

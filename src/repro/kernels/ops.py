@@ -36,6 +36,7 @@ from repro.kernels.fft4step import (
     auto_interpret,
     build_mega_call,
     build_spectral_call,
+    default_line_block as _line_block,
     line_exponents,
     remove_exponents,
     resolve_precision,
@@ -75,7 +76,7 @@ def spectral_op(
     fwd: bool = True,
     inv: bool = True,
     filter_mode: str = FILTER_NONE,
-    block: int = 8,
+    block: Optional[int] = None,
     fft_impl: str = "matmul",
     karatsuba: bool = False,
     precision: Optional[str] = None,
@@ -103,6 +104,7 @@ def spectral_op(
     deprecated pre-policy spelling of the same knob.
     """
     precision = resolve_precision(precision or compute_dtype).name
+    block = block or _line_block()
     batched = xr.ndim == 3
     if not batched:
         xr = xr[None]
@@ -174,7 +176,7 @@ def mega_spectral_op(
     segments,
     residency: str = RESIDENT_VMEM,
     batch_block: Optional[int] = None,
-    phase_block: int = 8,
+    phase_block: Optional[int] = None,
     buffer_depth: int = 2,
     fft_impl: str = "matmul",
     karatsuba: bool = False,
@@ -286,7 +288,7 @@ def mega_spectral_op(
 
     spec = MegaSpec(
         na=na, nr=nr, segments=tuple(segs), residency=residency,
-        batch_block=batch_block, phase_block=phase_block,
+        batch_block=batch_block, phase_block=phase_block or _line_block(),
         buffer_depth=buffer_depth, n1=n1, n2=n2,
         n3=n3, fft_impl=fft_impl, karatsuba=karatsuba, precision=precision)
     call = build_mega_call(spec, batch=b,

@@ -1,10 +1,10 @@
 """Multi-pod dry-run: AOT lower + compile every (arch x shape) cell on the
 production meshes, record memory/cost/collective analyses.
 
-The first two statements below MUST precede any other import (jax locks the
-device count on first init); this module is the only place the 512
-placeholder devices exist — tests and benches see the host's real device
-count.
+``main()`` asks for 512 placeholder host devices through XLA_FLAGS before
+its first device query (jax fixes the device count when the backend first
+initializes); importing this module changes nothing, so tests and benches
+see the host's real device count.
 
 Usage:
   python -m repro.launch.dryrun --arch yi-34b --shape train_4k --mesh single
@@ -12,12 +12,10 @@ Usage:
   python -m repro.launch.dryrun --arch sar-rda-4k --mesh multi   # the paper's
                                                                  # own workload
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import gzip
 import json
+import os
 import time
 import traceback
 
@@ -225,6 +223,9 @@ def main():
                          "scan-flops corrections)")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", ""))
 
     meshes = {"single": [False], "multi": [True], "both": [False, True]}
     cells = []

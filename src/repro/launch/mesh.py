@@ -10,29 +10,29 @@ shards over ("pod", "data"); parameters FSDP over "data" (intra-pod ICI),
 replicated across pods (gradient all-reduce is the only cross-pod
 collective, int8-compressible); tensor/expert parallel over "model".
 
-``jax.sharding.AxisType`` / the ``axis_types=`` kwarg only exist on newer
-jax; ``repro.compat.make_mesh`` drops them on 0.4.x where every mesh axis
-is implicitly Auto anyway.
+Every axis is ``AxisType.Auto``: the model code places activations with
+``with_sharding_constraint``, which ``jax.make_mesh``'s default Explicit
+axes refuse.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.compat import AXIS_TYPE_AUTO, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AXIS_TYPE_AUTO,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Whatever this host offers (tests / CPU examples)."""
     n = len(jax.devices())
     data = n // model
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AXIS_TYPE_AUTO, AXIS_TYPE_AUTO))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def batch_axes(mesh) -> tuple:

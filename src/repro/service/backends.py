@@ -37,6 +37,7 @@ shipped:
 """
 from __future__ import annotations
 
+import logging
 import time
 from collections import Counter
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -51,6 +52,8 @@ from repro.kernels.fft4step import resolve_precision
 from repro.service.queue import BatchKey
 from repro.service.resilience import BreakerBoard
 from repro import tuning
+
+_log = logging.getLogger(__name__)
 
 
 def _resolve_blocks(cfg, block: Optional[int], col_block: Optional[int]):
@@ -309,6 +312,9 @@ class LocalBackend:
             except Exception as e:          # noqa: BLE001 — tier boundary
                 br.record_failure()
                 last_err = e
+                _log.warning("route %s:%s failed for %dx%d: %s: %s", route,
+                             variant, key.scene.na, key.scene.nr,
+                             type(e).__name__, str(e).split("\n")[0][:300])
                 continue
             br.record_success()
             if (route, variant) != tiers[0]:
@@ -373,8 +379,11 @@ class LocalBackend:
             if br.allow():
                 try:
                     out = np.asarray(self._sharded_fn(key)(jnp.asarray(raw)))
-                except Exception:           # noqa: BLE001 — tier boundary
+                except Exception as e:      # noqa: BLE001 — tier boundary
                     br.record_failure()
+                    _log.warning("sharded route failed, streaming locally: "
+                                 "%s: %s", type(e).__name__,
+                                 str(e).split("\n")[0][:300])
                     self.fallbacks["serve:local_stream"] += 1
                 else:
                     br.record_success()

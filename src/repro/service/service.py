@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro import tuning
 from repro.core.sar.geometry import SceneConfig
 from repro.service import backends as backends_mod
 from repro.service.batcher import MicroBatcher
@@ -64,17 +65,23 @@ from repro.service.workers import Lane, WorkerPool
 _MAX_BISECT_DEPTH = 4
 
 
+# ServiceConfig.precision value meaning "the device's serving tier"
+DEVICE_TIER = "device"
+
+
 @dataclasses.dataclass(frozen=True)
 class ServiceConfig:
     """Service-level policy knobs (per-request knobs ride on the request).
 
     variant: default plan variant for requests that don't name one.
     precision: default precision tier for requests that don't name one.
-      The shipping default is 'bs16' (block-scaled f16 — per-line
-      exponents carried through the kernels, throughput tier); it is
-      still subject to the SNR gate like any explicit request. Set None
-      (or 'f32') for the full-precision verification path, which never
-      consults the gate.
+      The shipping default, DEVICE_TIER, takes the device table's
+      ``serving_tier`` (repro.tuning.cost.DEVICES): 'bs16' (block-scaled
+      f16, the throughput tier) where the matrix unit takes f16
+      operands, 'f32' on TPU v5e, whose MXU does not. A non-f32 default
+      is still subject to the SNR gate like any explicit request. Set
+      None (or 'f32') for the full-precision verification path, which
+      never consults the gate.
     backend: 'local' | 'sharded' (see repro.service.backends).
     max_batch: coalescing bound B — requests per micro-batch.
     max_delay_ms: deadline a lone request waits for batch company.
@@ -103,7 +110,7 @@ class ServiceConfig:
     """
 
     variant: str = "fused3"
-    precision: Optional[str] = "bs16"
+    precision: Optional[str] = DEVICE_TIER
     backend: str = "local"
     max_batch: int = 4
     max_delay_ms: float = 5.0
@@ -171,6 +178,11 @@ class FocusService:
     def __init__(self, config: ServiceConfig = ServiceConfig(),
                  backend=None, precision_deviation=None):
         self.config = config
+        # the default tier is fixed per device here, once: no breaker or
+        # fallback ever changes it (a tripped gate serves f32 per request)
+        self.default_precision = (
+            tuning.cost.device_spec().serving_tier
+            if config.precision == DEVICE_TIER else config.precision)
         self.metrics = ServiceMetrics()
         self.queue = RequestQueue(config.max_queue)
         if backend is None:
@@ -202,7 +214,7 @@ class FocusService:
         # streams, warms, gate measurements). Batches run under the
         # shared side of the pool's gate lock, gate measurements under
         # the exclusive side — the quality harness toggles the
-        # process-global x64 flag (compat.enable_x64 in simulate()),
+        # process-global x64 flag (jax.enable_x64 in simulate()),
         # which would corrupt a batch executing concurrently on another
         # lane. Lanes are (re)started by start() after a stop().
         self.pool = WorkerPool(lanes=config.lanes,
@@ -336,7 +348,8 @@ class FocusService:
         """Submit one scene; resolves to its focused (na, nr) image.
 
         ``precision=None`` takes the service's default tier
-        (``ServiceConfig.precision``, 'bs16' out of the box); pass 'f32'
+        (``ServiceConfig.precision``, the device's serving tier out of
+        the box — see DEVICE_TIER); pass 'f32'
         explicitly for the verification path. The resolved tier — default
         or per-request — is what the SNR gate checks and what the batcher
         coalesces on.
@@ -362,7 +375,7 @@ class FocusService:
             raise ValueError(f"deadline_ms must be > 0, got {deadline_ms}")
         explicit = precision is not None
         if precision is None:
-            precision = self.config.precision
+            precision = self.default_precision
         precision = await self._admit_precision(precision, explicit)
         raw = np.ascontiguousarray(np.asarray(raw, np.complex64))
         if raw.shape != (scene.na, scene.nr):

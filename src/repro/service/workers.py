@@ -23,7 +23,7 @@ and returns; when a lane's slots are full the hand-off parks, which is
 the in-flight-cap backpressure that lets the queue backlog coalesce.
 
 Device-global serialization: the SNR-gate quality harness toggles the
-process-global x64 flag (compat.enable_x64 inside simulate()), which
+process-global x64 flag (jax.enable_x64 inside simulate()), which
 would corrupt any batch executing concurrently on another lane. Lanes
 therefore run batches under the read side of a reader-writer lock and
 gate measurements (plus warms) take the write side — many concurrent

@@ -30,16 +30,19 @@ in-VMEM operand cast narrows).
 
 **VMEM feasibility.** A grid step must hold its x/y slabs (double for
 the out-of-place stages), the DFT constants, and the filter block inside
-the ~16 MiB VMEM budget — the TPU analogue of the paper's 32 KiB
+the device's VMEM budget (``DeviceSpec.vmem_budget_bytes``, 64 MiB on
+v5e) — the TPU analogue of the paper's 32 KiB
 threadgroup-memory constraint. Infeasible candidates are cut before
 ranking; the cut can never empty a candidate set that contains the
 library default (tested).
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Optional
 
+from repro.kernels import fft4step
 from repro.kernels.fft4step import (
     MAX_FACTOR,
     RESIDENT_STAGED,
@@ -59,15 +62,77 @@ from repro.tuning.space import (
     bucket_batch,
 )
 
-# Nominal device constants. Ranking, not prediction, is the contract:
-# these are TPU-class magnitudes (peak matrix FLOP/s, HBM bytes/s, VMEM
-# bytes) whose RATIO sets the roofline ridge; absolute wall-clock on any
-# one device is calibrated away by the measured rungs that follow.
-PEAK_MATMUL_FLOPS = 2.0e14      # dense f32 matrix throughput
-PEAK_VPU_FLOPS = 4.0e12         # pointwise (twiddle/filter) throughput
-PEAK_HBM_BYTES = 1.2e12         # HBM <-> VMEM bandwidth
-VMEM_BUDGET_BYTES = 16 * 2**20  # per-grid-step on-chip footprint budget
-PEAK_LINK_BYTES = 5.0e10        # per-device inter-chip (ICI-class) b/w
+@dataclasses.dataclass(frozen=True)
+class DeviceSpec:
+    """What the cost model and the kernel build functions know about one
+    device kind. Peaks price the roofline (ranking candidates, never predicting
+    a wall time); the VMEM fields size Mosaic limits and residency cuts;
+    the rest are per-device defaults the code chooses from."""
+
+    kind: str                 # jax.devices()[0].device_kind
+    peak_matmul_flops: float  # f32 (HIGHEST) matrix throughput, FLOP/s
+    peak_vpu_flops: float     # pointwise (twiddle/filter) FLOP/s
+    peak_hbm_bytes: float     # HBM <-> VMEM bytes/s
+    peak_link_bytes: float    # per-chip inter-chip bytes/s
+    hbm_bytes: int            # device memory
+    vmem_bytes: int           # physical VMEM: the hard cap of any limit
+    vmem_budget_bytes: int    # per-grid-step budget of residency cuts
+    f16_operands: bool        # the matrix unit takes float16 operands
+    serving_tier: str         # the service's default precision tier
+    line_block: int           # default rows line block / staged phase block
+    source: str
+
+
+DEVICES = {d.kind: d for d in (
+    DeviceSpec(
+        kind="TPU v5 lite",
+        # 197e12 is the published bf16 peak; an f32 HIGHEST matmul runs
+        # as 6 bf16 passes (assumed), so the f32 rate is a sixth of it
+        peak_matmul_flops=197e12 / 6,
+        peak_vpu_flops=4.0e12,            # assumed, not published
+        peak_hbm_bytes=819e9,
+        peak_link_bytes=1600e9 / 8,       # 1,600 Gbit/s ICI
+        hbm_bytes=16 * 10**9,
+        vmem_bytes=128 * 2**20,
+        vmem_budget_bytes=64 * 2**20,
+        f16_operands=False,               # Mosaic cannot pack f32 to f16
+        serving_tier="f32",
+        line_block=128,                   # lane-aligned in-VMEM transposes
+        source="Google Cloud TPU v5e documentation: 197 TFLOP/s bf16, "
+               "393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI; "
+               "128 MiB VMEM per core (jax pallas tpu_info)"),
+    DeviceSpec(
+        kind="cpu",
+        # the Pallas interpreter on the host: TPU-class magnitudes whose
+        # ratios rank candidates, and the v5e VMEM, so interpret-mode
+        # runs take the residency routes the chip would
+        peak_matmul_flops=2.0e14,
+        peak_vpu_flops=4.0e12,
+        peak_hbm_bytes=1.2e12,
+        peak_link_bytes=5.0e10,
+        hbm_bytes=16 * 10**9,
+        vmem_bytes=128 * 2**20,
+        vmem_budget_bytes=64 * 2**20,
+        f16_operands=True,                # interpret mode emulates f16
+        serving_tier="bs16",
+        line_block=8,                     # no lane alignment to honour
+        source="interpret-mode row: nominal constants, not a device"),
+)}
+
+
+def device_spec(kind: Optional[str] = None) -> DeviceSpec:
+    """The table row for ``kind`` (default: the first JAX device's kind).
+    A device the table does not list is an error, never a default."""
+    if kind is None:
+        kind = fft4step.device_kind()
+    try:
+        return DEVICES[kind]
+    except KeyError:
+        raise KeyError(
+            f"device kind {kind!r} is not in repro.tuning.cost.DEVICES "
+            f"({sorted(DEVICES)}); add a row with its published peaks"
+        ) from None
+
 
 # Matmul-throughput multiplier per operand precision ("Range, Not
 # Precision": narrow operands double matrix-unit throughput; bs16 spends
@@ -90,17 +155,16 @@ def _const_bytes(factors: tuple) -> int:
 
 
 def vmem_bytes(config: KernelConfig, key: TuneKey) -> int:
-    """Approximate per-grid-step VMEM footprint of one fused dispatch."""
-    n = key.n
-    block = config.block or 8
-    slab = 2 * 4 * block * key.batch * n     # split re/im f32, one slab
-    # x in + y out + one out-of-place intermediate per live stage pair
-    footprint = 3 * slab
-    footprint += _const_bytes(_factors(config, n))
-    footprint += 2 * 4 * n                   # shared filter vector block
-    if resolve_precision(config.precision).block_scaled:
-        footprint += slab // 2               # f16 scaled copy of the slab
-    return footprint
+    """Per-grid-step VMEM footprint of one fused rows dispatch under
+    ``config`` — the kernel's own estimate (fft4step.spectral_vmem_bytes),
+    the same number its Mosaic VMEM limit is sized from."""
+    fs = _factors(config, key.n) + (None,) * 3
+    spec = SpectralSpec(
+        n=key.n, fwd=True, inv=True, filter_mode="shared",
+        block=config.block or device_spec().line_block,
+        n1=fs[0], n2=fs[1], n3=fs[2],
+        precision=resolve_precision(config.precision).name)
+    return fft4step.spectral_vmem_bytes(spec, batch_block=key.batch)
 
 
 def structurally_feasible(config: KernelConfig, key: TuneKey) -> bool:
@@ -111,7 +175,7 @@ def structurally_feasible(config: KernelConfig, key: TuneKey) -> bool:
         return False
     if any(f > MAX_FACTOR or f & (f - 1) for f in fs):
         return False
-    block = config.block or 8
+    block = config.block or device_spec().line_block
     # ops.spectral_op PADS lines up to a block multiple, so a block that
     # does not divide lines is still runnable (the pad is timed, and
     # priced, honestly); only block > lines is pure waste — the whole
@@ -121,11 +185,16 @@ def structurally_feasible(config: KernelConfig, key: TuneKey) -> bool:
     return True
 
 
+def _budget(vmem_budget: Optional[int]) -> int:
+    return (device_spec().vmem_budget_bytes if vmem_budget is None
+            else vmem_budget)
+
+
 def feasible(config: KernelConfig, key: TuneKey,
-             vmem_budget: int = VMEM_BUDGET_BYTES) -> bool:
+             vmem_budget: Optional[int] = None) -> bool:
     """Structural + footprint feasibility cut (never measured if False)."""
     return structurally_feasible(config, key) and \
-        vmem_bytes(config, key) <= vmem_budget
+        vmem_bytes(config, key) <= _budget(vmem_budget)
 
 
 def _dispatch_terms(*, n: int, lines: int, batch: int, factors: tuple,
@@ -140,7 +209,8 @@ def _dispatch_terms(*, n: int, lines: int, batch: int, factors: tuple,
     bit-identical arithmetic."""
     lines_total = batch * lines
     prec = resolve_precision(precision)
-    matmul_rate = PEAK_MATMUL_FLOPS * _PRECISION_SPEEDUP[prec.name]
+    dev = device_spec()
+    matmul_rate = dev.peak_matmul_flops * _PRECISION_SPEEDUP[prec.name]
 
     # compute: per-stage dense-DFT matmuls at factor-dependent efficiency
     mac_flops = 6.0 if karatsuba else 8.0
@@ -154,7 +224,7 @@ def _dispatch_terms(*, n: int, lines: int, batch: int, factors: tuple,
     pointwise = transforms * (len(factors) - 1) * 6.0 * n * lines_total
     if filtered:
         pointwise += 6.0 * n * lines_total
-    vpu = pointwise / PEAK_VPU_FLOPS
+    vpu = pointwise / dev.peak_vpu_flops
     compute = matmul + vpu
 
     # memory: slab in+out once per dispatch, constants once per grid step
@@ -163,7 +233,7 @@ def _dispatch_terms(*, n: int, lines: int, batch: int, factors: tuple,
     bytes_moved += grid_steps * _const_bytes(factors)
     if filtered:
         bytes_moved += 2 * 4 * n                       # shared filter
-    memory = bytes_moved / PEAK_HBM_BYTES
+    memory = bytes_moved / dev.peak_hbm_bytes
 
     return {
         "matmul_seconds": matmul,
@@ -189,14 +259,14 @@ def predicted_seconds(config: KernelConfig, key: TuneKey,
         factors=_factors(config, key.n), karatsuba=config.karatsuba,
         precision=config.precision,
         transforms=(1 if fwd else 0) + (1 if inv else 0),
-        filtered=filtered, block=config.block or 8)
+        filtered=filtered, block=config.block or device_spec().line_block)
     return terms["predicted_seconds"]
 
 
 def cost_breakdown(config: KernelConfig, key: TuneKey,
                    fwd: bool = True, inv: bool = True,
                    filtered: bool = True,
-                   vmem_budget: int = VMEM_BUDGET_BYTES) -> dict:
+                   vmem_budget: Optional[int] = None) -> dict:
     """The itemized cost-model verdict on one candidate — what the CLI's
     ``--explain`` prints so schedule choices are debuggable: matmul vs
     VPU vs bytes seconds, the roofline total, and both feasibility cuts."""
@@ -205,11 +275,11 @@ def cost_breakdown(config: KernelConfig, key: TuneKey,
         factors=_factors(config, key.n), karatsuba=config.karatsuba,
         precision=config.precision,
         transforms=(1 if fwd else 0) + (1 if inv else 0),
-        filtered=filtered, block=config.block or 8)
+        filtered=filtered, block=config.block or device_spec().line_block)
     vb = vmem_bytes(config, key)
     terms.update({
         "vmem_bytes": vb,
-        "vmem_feasible": vb <= vmem_budget,
+        "vmem_feasible": vb <= _budget(vmem_budget),
         "structurally_feasible": structurally_feasible(config, key),
     })
     return terms
@@ -221,50 +291,50 @@ def cost_breakdown(config: KernelConfig, key: TuneKey,
 #
 # The single-dispatch megakernel has two execution modes and ONE decision:
 # does a whole (Bb, na, nr) scene slab — plus both axes' DFT constants and
-# the resident filter payloads — fit the ~16 MiB VMEM budget? If yes, the
+# the resident filter payloads — fit the device's VMEM budget? If yes, the
 # VMEM-resident mode realizes the paper's zero-HBM-intermediate claim; if
 # not, the scratch-staged two-phase layout keeps the dispatch count at 1
 # while double-buffered DMA hides the corner-turn traffic. This is the
 # paper's 32 KiB threadgroup-memory cut, one tier up.
 
+def _mega_spec(na: int, nr: int, shapes, *, residency: str,
+               precision=None, phase_block: Optional[int] = None,
+               buffer_depth: Optional[int] = None,
+               seg_configs=None) -> "fft4step.MegaSpec":
+    """A filterless MegaSpec with the given segment shapes: what the
+    footprint estimate needs (filters are priced from their bytes)."""
+    segs = []
+    for i, shape in enumerate(shapes):
+        sc = seg_configs[i] if seg_configs is not None else SegmentConfig()
+        segs.append(fft4step.SegmentSpec(
+            axis=shape.axis, fwd=shape.fwd, inv=shape.inv,
+            filter_mode=("none" if shape.fwd or shape.inv else "shared"),
+            n1=sc.n1, n2=sc.n2, n3=sc.n3))
+    return fft4step.MegaSpec(
+        na=na, nr=nr, segments=tuple(segs), residency=residency,
+        phase_block=phase_block or device_spec().line_block,
+        buffer_depth=buffer_depth or 2,
+        precision=resolve_precision(precision).name)
+
+
 def mega_vmem_bytes(na: int, nr: int, batch_block: int = 1,
                     precision: Optional[str] = None,
                     filter_bytes: int = 0) -> int:
-    """Approximate VMEM footprint of one VMEM-resident megakernel grid
-    step: the split re/im slab x3 (in + out + one out-of-place stage
-    intermediate), both axes' DFT constants, and the filter payloads."""
-    slab = 2 * 4 * batch_block * na * nr
-    footprint = 3 * slab
-    footprint += _const_bytes(default_factorization(nr))
-    footprint += _const_bytes(default_factorization(na))
-    footprint += filter_bytes
-    if resolve_precision(precision).block_scaled:
-        footprint += slab // 2               # f16 scaled copy of the slab
-    return footprint
-
-
-def staged_vmem_bytes(na: int, nr: int, phase_block: int = 8,
-                      filter_bytes: int = 0) -> int:
-    """VMEM footprint of the scratch-staged two-phase layout: the
-    double-buffered row and column line slabs (2 slots x re/im each, and
-    potentially a FULL-filter slab alongside), plus DFT constants for
-    both axes. The scene itself lives in the HBM scratch."""
-    pb_r = min(phase_block, na)
-    pb_c = min(phase_block, nr)
-    bufs = 2 * 2 * 4 * (pb_r * nr + na * pb_c)
-    bufs *= 2                                # worst case: FULL-filter slabs
-    bufs += _const_bytes(default_factorization(nr))
-    bufs += _const_bytes(default_factorization(na))
-    return bufs + filter_bytes
+    """VMEM footprint of one VMEM-resident megakernel grid step for the
+    canonical azimuth -> range -> azimuth chain (the kernel's estimate,
+    fft4step.mega_vmem_bytes), plus the double-buffered filter payloads."""
+    spec = _mega_spec(na, nr, _MEGA_SEGMENTS_2D, residency=RESIDENT_VMEM,
+                      precision=precision)
+    return fft4step.mega_vmem_bytes(spec, batch_block) + 2 * filter_bytes
 
 
 def mega_residency(na: int, nr: int, batch_block: int = 1,
                    precision: Optional[str] = None, filter_bytes: int = 0,
-                   vmem_budget: int = VMEM_BUDGET_BYTES) -> str:
+                   vmem_budget: Optional[int] = None) -> str:
     """The residency mode the compiler picks when none is pinned: VMEM-
     resident iff the whole slab fits the budget, else scratch-staged."""
     fits = mega_vmem_bytes(na, nr, batch_block, precision,
-                           filter_bytes) <= vmem_budget
+                           filter_bytes) <= _budget(vmem_budget)
     return RESIDENT_VMEM if fits else RESIDENT_STAGED
 
 
@@ -312,12 +382,13 @@ def segment_seconds(problem: ScheduleProblem, shape: SegmentShape,
             n=n, lines=lines, batch=problem.batch, factors=fs,
             karatsuba=kara, precision=precision, transforms=transforms,
             filtered=shape.filtered, block=lines)
-        return terms["compute_seconds"] + _const_bytes(fs) / PEAK_HBM_BYTES
+        return (terms["compute_seconds"]
+                + _const_bytes(fs) / device_spec().peak_hbm_bytes)
     eff_block = phase_block if problem.mega else block
     terms = _dispatch_terms(
         n=n, lines=lines, batch=problem.batch, factors=fs,
         karatsuba=kara, precision=precision, transforms=transforms,
-        filtered=shape.filtered, block=eff_block or 8)
+        filtered=shape.filtered, block=eff_block or device_spec().line_block)
     return terms["predicted_seconds"]
 
 
@@ -356,7 +427,7 @@ def turn_seconds(problem: ScheduleProblem, *,
     Sharded (devices > 1): every turn is a dispatch-boundary all_to_all
     regardless of residency — each device writes its 1/P slab out, moves
     (P-1)/P of it over inter-chip links, and reads the re-sharded slab
-    back. The link term dominates (PEAK_LINK_BYTES << PEAK_HBM_BYTES);
+    back. The link term dominates (peak_link_bytes < peak_hbm_bytes);
     with ``buffer_depth >= 2`` the staged megakernel's double-buffered
     DMA phases earn the same TURN_OVERLAP credit as the local tier (the
     collective for block j+1 overlaps block j's DFT matmuls)."""
@@ -366,14 +437,15 @@ def turn_seconds(problem: ScheduleProblem, *,
         wire = collective_turn_bytes(problem.na, problem.nr,
                                      problem.batch, p,
                                      precision=precision)
-        secs = slab * 2 / PEAK_HBM_BYTES + wire / PEAK_LINK_BYTES
+        dev = device_spec()
+        secs = slab * 2 / dev.peak_hbm_bytes + wire / dev.peak_link_bytes
         overlap = TURN_OVERLAP if (buffer_depth or 2) >= 2 else 1.0
         return secs * overlap
     if residency != RESIDENT_STAGED:
         return 0.0
     traffic = 2 * 2 * 4 * problem.na * problem.nr * problem.batch
     overlap = TURN_OVERLAP if (buffer_depth or 2) >= 2 else 1.0
-    return traffic / PEAK_HBM_BYTES * overlap
+    return traffic / device_spec().peak_hbm_bytes * overlap
 
 
 def schedule_vmem_bytes(schedule: Schedule,
@@ -382,41 +454,25 @@ def schedule_vmem_bytes(schedule: Schedule,
     """Per-grid-step VMEM footprint of a whole schedule.
 
     Flat problems defer to `vmem_bytes` via the flat-config view. Mega
-    problems price the residency tier's slabs plus one set of DFT
-    constants per DISTINCT (axis, factorization) — per-segment
-    factorizations that agree share their constants, differing ones
-    each pay."""
+    problems price the megakernel the schedule would build (one set of DFT
+    constants per distinct (axis, factorization), the residency tier's
+    slabs or line buffers) with the kernel's own estimate; a sharded
+    problem's resident slab holds 1/P of the scene's lines."""
     if not problem.mega:
         key = TuneKey(kind="kernel", backend="-", device="-",
                       n=problem.nr, batch=bucket_batch(problem.batch),
                       lines=problem.na)
         return vmem_bytes(schedule.to_config(), key)
-    const = 0
-    seen = set()
-    for i, shape in enumerate(problem.segments):
-        fs = schedule.segment(i).factors() or default_factorization(
-            problem.seg_n(shape))
-        if (shape.axis, fs) in seen:
-            continue
-        seen.add((shape.axis, fs))
-        const += _const_bytes(fs)
-    if schedule.residency == RESIDENT_STAGED:
-        pb = schedule.phase_block or 8
-        pb_r = min(pb, problem.na)
-        pb_c = min(pb, problem.nr)
-        depth = schedule.buffer_depth or 2
-        bufs = depth * 2 * 4 * (pb_r * problem.nr + problem.na * pb_c)
-        bufs *= 2                        # worst case: FULL-filter slabs
-        return bufs + const + filter_bytes
-    # devices > 1: each device's VMEM holds a 1/P slab (the staged line
-    # buffers above are NOT divided — their long axis is the transform
-    # axis, which sharding never splits)
-    slab = 2 * 4 * problem.batch * problem.na * problem.nr \
-        // problem.devices
-    footprint = 3 * slab + const + filter_bytes
-    if resolve_precision(schedule.precision).block_scaled:
-        footprint += slab // 2
-    return footprint
+    residency = schedule.residency or RESIDENT_VMEM
+    spec = _mega_spec(
+        problem.na, problem.nr, problem.segments, residency=residency,
+        precision=schedule.precision, phase_block=schedule.phase_block,
+        buffer_depth=schedule.buffer_depth,
+        seg_configs=[schedule.segment(i)
+                     for i in range(len(problem.segments))])
+    bb = problem.batch if residency == RESIDENT_VMEM else 1
+    return (fft4step.mega_vmem_bytes(spec, bb, devices=problem.devices)
+            + 2 * filter_bytes)
 
 
 def schedule_structurally_feasible(schedule: Schedule,
@@ -430,7 +486,7 @@ def schedule_structurally_feasible(schedule: Schedule,
         if any(f > MAX_FACTOR or f & (f - 1) for f in fs):
             return False
     if not problem.mega:
-        block = schedule.block or 8
+        block = schedule.block or device_spec().line_block
         lines = problem.na
         if block > lines and lines % block:
             return False
@@ -439,10 +495,11 @@ def schedule_structurally_feasible(schedule: Schedule,
 
 def schedule_feasible(schedule: Schedule, problem: ScheduleProblem,
                       filter_bytes: int = 0,
-                      vmem_budget: int = VMEM_BUDGET_BYTES) -> bool:
+                      vmem_budget: Optional[int] = None) -> bool:
     """Structural + VMEM feasibility of a complete schedule path."""
     return schedule_structurally_feasible(schedule, problem) and \
-        schedule_vmem_bytes(schedule, problem, filter_bytes) <= vmem_budget
+        schedule_vmem_bytes(schedule, problem, filter_bytes) <= \
+        _budget(vmem_budget)
 
 
 def schedule_seconds(schedule: Schedule,
@@ -469,7 +526,7 @@ def schedule_seconds(schedule: Schedule,
         # 1/P of it per device when sharded
         slab_io = (2 * 2 * 4 * problem.na * problem.nr * problem.batch
                    / problem.devices)
-        total += slab_io / PEAK_HBM_BYTES
+        total += slab_io / device_spec().peak_hbm_bytes
     return total
 
 
@@ -492,7 +549,8 @@ def _default_mega_schedule(na: int, nr: int, devices: int = 1,
                          precision=precision, filter_bytes=filter_bytes)
     return Schedule(segments=(SegmentConfig(),) * len(_MEGA_SEGMENTS_2D),
                     precision=precision, residency=res,
-                    phase_block=8, buffer_depth=2)
+                    phase_block=device_spec().line_block,
+                    buffer_depth=2)
 
 
 def sharded_preferred(na: int, nr: int, batch: int = 1, devices: int = 1,
@@ -505,7 +563,7 @@ def sharded_preferred(na: int, nr: int, batch: int = 1, devices: int = 1,
     Prices the canonical azimuth->range->azimuth megakernel both ways
     with `schedule_seconds`: locally the corner turns are free (VMEM) or
     HBM-priced (staged); sharded they become all_to_all collectives
-    (`collective_turn_bytes` over PEAK_LINK_BYTES) but every compute and
+    (`collective_turn_bytes` over peak_link_bytes) but every compute and
     slab-I/O term divides by P. Scenes whose whole slab fits the local
     VMEM budget never shard — the local single-dispatch megakernel route
     already serves them with zero HBM intermediates, and a collective
@@ -547,7 +605,8 @@ def serve_batch_seconds(na: int, nr: int, batch: int = 1,
            else mega_residency(na, nr, precision=precision))
     sched = Schedule(
         segments=(SegmentConfig(),) * len(_MEGA_SEGMENTS_2D),
-        precision=precision, residency=res, phase_block=8, buffer_depth=2)
+        precision=precision, residency=res,
+        phase_block=device_spec().line_block, buffer_depth=2)
     return schedule_seconds(sched, problem)
 
 
@@ -561,7 +620,7 @@ def nominal_flops(key: TuneKey, fwd: bool = True, inv: bool = True,
     return _flops_per_line(spec) * key.batch * key.lines
 
 
-def rank(configs, key: TuneKey, vmem_budget: int = VMEM_BUDGET_BYTES,
+def rank(configs, key: TuneKey, vmem_budget: Optional[int] = None,
          **kw) -> list:
     """Feasible configs sorted by predicted cost, cheapest first.
 

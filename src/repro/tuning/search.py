@@ -35,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import itertools
+import logging
 import math
 import time
 from typing import Callable, Optional, Sequence
@@ -99,6 +100,15 @@ class SearchResult:
     # trace rows: (config, seconds | None if infeasible at measure time)
     schedule: Optional[Schedule] = None   # the winner as a Schedule
 
+    @property
+    def dropped(self) -> int:
+        """Candidates whose measure raised (each one logged as a warning
+        by :func:`measured_search`)."""
+        return sum(t is None for _, t in self.trace)
+
+
+_log = logging.getLogger(__name__)
+
 
 def measured_search(cands: Sequence, measure: Callable,
                     order: Optional[Callable] = None,
@@ -107,12 +117,15 @@ def measured_search(cands: Sequence, measure: Callable,
                     log: Optional[Callable] = None):
     """Successive-halving over ``cands``.
 
-    measure(candidate, iters) -> wall seconds (may raise: the candidate is
-    dropped as infeasible). ``order`` ranks candidates cheapest-first
-    without running them (the cost model); ``max_measure`` caps how many
-    enter rung 0. Each rung times the survivors with ``rungs[i]``
-    iterations and keeps the fastest half. Returns
-    (best_candidate, best_seconds, trace) with trace = [(cand, secs|None)].
+    measure(candidate, iters) -> wall seconds. A candidate whose measure
+    raises (typically a kernel the compiler refuses) is dropped, and the
+    drop is never silent: it lands in the trace as (cand, None), whatever
+    the rung, and in the module logger as a warning with the error.
+    ``order`` ranks candidates cheapest-first without
+    running them (the cost model); ``max_measure`` caps how many enter
+    rung 0. Each rung times the survivors with ``rungs[i]`` iterations and
+    keeps the fastest half. Returns (best_candidate, best_seconds, trace)
+    with trace = [(cand, secs|None)].
     """
     pool = list(cands)
     if order is not None:
@@ -127,9 +140,11 @@ def measured_search(cands: Sequence, measure: Callable,
         for i, cand in enumerate(survivors):
             try:
                 t = measure(cand, iters)
-            except Exception:
-                if r == 0:
-                    trace.append((cand, None))
+            except Exception as e:       # noqa: BLE001 — traced + logged
+                _log.warning("candidate %r dropped at rung %d: %s: %s",
+                             cand, r, type(e).__name__,
+                             str(e).split("\n")[0][:300])
+                trace.append((cand, None))
                 continue
             trace.append((cand, t))
             timed.append((t, i, cand))
@@ -199,7 +214,7 @@ def schedule_frontier(problem: ScheduleProblem, *,
                       phase_blocks: Sequence[int] = (8,),
                       buffer_depths: Sequence[int] = (2,),
                       filter_bytes: int = 0,
-                      vmem_budget: int = costlib.VMEM_BUDGET_BYTES
+                      vmem_budget: Optional[int] = None
                       ) -> list:
     """Solve the schedule DAG: the ``k`` cheapest complete schedules in
     increasing predicted cost (``k=None`` enumerates the whole space —
@@ -253,7 +268,8 @@ def schedule_frontier(problem: ScheduleProblem, *,
             # a sharded problem's corner-turn collectives are priced in
             # costlib.turn_seconds via problem.devices)
             base += (2 * 2 * 4 * problem.na * problem.nr * problem.batch
-                     / problem.devices / costlib.PEAK_HBM_BYTES)
+                     / problem.devices
+                     / costlib.device_spec().peak_hbm_bytes)
         heapq.heappush(heap, (base, next(counter), i, ()))
 
     feasible: list = []

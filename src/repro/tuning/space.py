@@ -67,11 +67,10 @@ def bucket_batch(b: int) -> int:
 
 def device_fingerprint() -> str:
     """The device kind the process would tune on (first jax device),
-    sanitized for use inside an encoded cache key."""
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = "unknown"
+    sanitized for use inside an encoded cache key. A process with no
+    device raises: a tuning result keyed "unknown" would be served to
+    whatever device reads it next."""
+    kind = jax.devices()[0].device_kind
     return str(kind).strip().replace(" ", "-").replace("|", "-")
 
 

@@ -23,13 +23,14 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900):
 def test_distributed_sar_corner2_and_halo():
     out = run_sub("""
 import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.core.sar import test_scene, paper_targets, simulate, build_pipeline, metrics
 from repro.core.sar.distributed import build_corner2, build_halo
 
 cfg = test_scene(256)
 targets = paper_targets(cfg)
 raw = simulate(cfg, targets)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 
 f3 = np.asarray(build_pipeline(cfg, "fused3").run(raw))
 img = np.asarray(build_corner2(cfg, mesh)(raw))
@@ -42,7 +43,8 @@ assert c["l2_relative_error"] < 1e-5, c["l2_relative_error"]
 assert max(c["snr_delta_db"]) < 0.01
 
 # multi-axis mesh (pod x data)
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+mesh2 = jax.make_mesh((2, 4), ("pod", "data"),
+                      axis_types=(AxisType.Auto,) * 2)
 img2 = np.asarray(build_corner2(cfg, mesh2, axes=("pod", "data"))(raw))
 assert float(np.max(np.abs(img2 - img))) == 0.0
 print("DIST_SAR_OK")
@@ -54,16 +56,16 @@ print("DIST_SAR_OK")
 def test_compressed_psum_matches_mean():
     out = run_sub("""
 import numpy as np, jax, jax.numpy as jnp, functools
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.optim import compress
 
-mesh = jax.make_mesh((8,), ("dp",))
+mesh = jax.make_mesh((8,), ("dp",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.standard_normal((8, 64)), jnp.float32)
 e = jnp.zeros((8, 64), jnp.float32)
 
-@functools.partial(shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")),
                    out_specs=(P("dp"), P("dp")))
 def f(gl, el):
     m, ne = compress.compressed_psum({"g": gl}, {"g": el}, "dp")
@@ -86,6 +88,7 @@ def test_lm_sharded_train_step_matches_single_device():
     """One train step under a 4x2 (data x model) mesh == single-device."""
     out = run_sub("""
 import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs import registry
 from repro.launch import sharding as shd
 from repro.launch.mesh import activation_rules
@@ -113,7 +116,8 @@ p1, s1, st1 = jax.jit(step)(params, opt, batch)
 # `step` would silently reuse the jaxpr traced OUTSIDE the context — no
 # sharding constraints, no ZeRO-3 use-site gather, and bf16 partial-sum
 # contractions over the FSDP-sharded dims that drift the loss by units.
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = activation_rules(mesh)
 p_sh = shd.param_shardings(params, cfg, mesh, rules)
 params_s = jax.device_put(params, p_sh)
@@ -137,6 +141,7 @@ def test_long_decode_seq_parallel_kv():
     """Batch-1 decode with a sequence-sharded KV cache == single device."""
     out = run_sub("""
 import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs import registry
 from repro.launch import sharding as shd
 from repro.launch.mesh import activation_rules
@@ -150,7 +155,8 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (1, 64), 0,
 cache, _ = model.prefill(params, {"tokens": toks[:, :63]}, max_len=64)
 l1, _ = model.decode_step(params, cache, toks[:, 63:64])
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = activation_rules(mesh)
 with use_mesh_rules(mesh, rules):
     c_sh = shd.cache_shardings(jax.eval_shape(lambda: cache), cfg, mesh,

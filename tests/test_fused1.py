@@ -183,7 +183,8 @@ def test_lower_sharded_accepts_mega_step():
     boundaries into per-device segment groups: 3 megakernel dispatches
     per device, the 2 turns now collectives — and on a 1-device mesh the
     result stays bit-identical to the local fused3 reference."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     p = build_pipeline(CFG, "fused1", tune="off")
     run = p.lower_sharded(mesh)
     assert run.devices == 1
@@ -198,7 +199,8 @@ def test_lower_sharded_accepts_mega_step():
 def test_lower_sharded_rejects_transposing_plan():
     """Transpose stages reorder the whole scene — no per-device slab can
     do that locally, and the error must say what to compile instead."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     p = build_pipeline(CFG, "fused", tune="off")   # transposing variant
     with pytest.raises(ValueError, match="fused1"):
         p.lower_sharded(mesh)

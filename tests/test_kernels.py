@@ -307,3 +307,19 @@ def test_fused_equals_composed(shape, seed):
     mr, mi = fr * hr - fi * hi, fr * hi + fi * hr
     want = ops.ifft_rows(mr, mi, block=2)
     assert_close(fused, (np.asarray(want[0]), np.asarray(want[1])), tol=1e-3)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_auto_interpret_by_backend(monkeypatch, backend, interpret):
+    """Interpret mode only on the CPU, compiled on a TPU, and any other
+    backend is an error rather than a silent interpreter fallback."""
+    from repro.kernels import fft4step
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert fft4step.auto_interpret(True) is True
+    assert fft4step.auto_interpret(False) is False
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            fft4step.auto_interpret(None)
+    else:
+        assert fft4step.auto_interpret(None) is interpret

@@ -182,7 +182,8 @@ golden = json.load(open({GOLDEN_PATH!r}))["families"]["rda"]["targets"]
 cfg = test_scene({N})
 targets = paper_targets(cfg)
 raw = jnp.asarray(np.asarray(simulate_cached(cfg, targets), np.complex64))
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 for precision, exact in ((None, True), ("bs16", False)):
     kw = {{"tune": "off"}}

@@ -592,9 +592,9 @@ def test_f32_requests_never_consult_the_gate():
 
 
 def test_default_serving_tier_is_bs16():
-    """Out of the box the service serves the block-scaled throughput
-    tier: an un-annotated request resolves to ServiceConfig.precision
-    ('bs16') — still gated — and an explicit precision='f32' request
+    """Out of the box the service serves the device's tier, on the CPU
+    the block-scaled throughput tier: an un-annotated request resolves to
+    'bs16' — still gated — and an explicit precision='f32' request
     takes the full-precision verification path. Both ride the fused1
     route, so each must equal its per-axis fused3 reference bit-exact."""
     raw = scene()
@@ -613,6 +613,20 @@ def test_default_serving_tier_is_bs16():
     assert np.array_equal(tier, reference(precision="bs16"))
     assert np.array_equal(verify, reference())
     assert not np.array_equal(tier, verify)
+
+
+@pytest.mark.parametrize("kind,tier", [("cpu", "bs16"),
+                                       ("TPU v5 lite", "f32")])
+def test_default_tier_is_chosen_per_device(monkeypatch, kind, tier):
+    """ServiceConfig.precision='device' (the default) resolves once, from
+    the device table, to the device's serving tier; v5e's MXU takes no
+    f16 operands, so its tier is f32. An explicit precision is kept."""
+    from repro.kernels import fft4step
+    monkeypatch.setattr(fft4step, "device_kind", lambda: kind)
+    assert FocusService(ServiceConfig(),
+                        backend=fast_backend()).default_precision == tier
+    assert FocusService(ServiceConfig(precision="bf16"),
+                        backend=fast_backend()).default_precision == "bf16"
 
 
 def test_service_restarts_after_stop():
@@ -659,7 +673,8 @@ def test_halo_schedule_rejects_unsupported_options():
     """The halo schedule must refuse precision/turn_dtype rather than
     silently serving unlabelled f32 results."""
     from repro.core.sar.distributed import build_sharded
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     with pytest.raises(ValueError, match="precision"):
         build_sharded(CFG, "fused3", mesh, schedule="halo",
                       precision="bf16")
@@ -856,7 +871,8 @@ def test_sharded_backend_reachable_and_matches_local():
     ref = reference()
 
     async def main():
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         svc = FocusService(
             ServiceConfig(backend="sharded", max_batch=2,
                           max_delay_ms=200.0, precision=None),
@@ -889,7 +905,8 @@ from repro.service import FocusService, ServiceConfig, ShardedBackend
 cfg = test_scene(256)
 targets = paper_targets(cfg)
 raw = simulate_cached(cfg, targets)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 local = np.asarray(build_pipeline(cfg, "fused3").run(jnp.asarray(raw)))
 

@@ -238,7 +238,8 @@ import repro.core.sar.csa, repro.core.sar.omegak  # register variants
 cfg = test_scene(256)
 targets = paper_targets(cfg)
 raw = jnp.asarray(simulate(cfg, targets))
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 for variant, twin in (("fused1", "fused3"), ("csa_fused1", "csa_fused"),
                       ("omegak_fused1", "omegak")):

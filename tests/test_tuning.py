@@ -379,6 +379,23 @@ def test_search_times_strictly_fewer_candidates_and_finds_best(tmp_path):
     assert tuning.cached_config(512, 1, cache=cache) == best
 
 
+def test_search_counts_candidates_that_failed_to_compile():
+    """A candidate the compiler refuses is counted on the result, not
+    lost: the winner comes from the rest."""
+    key = tuning.TuneKey.kernel(512, 1)
+    ranked = cost.rank(tuning.candidates(512), key)
+    refused = ranked[0]
+
+    def measure(cand, iters):
+        if cand == refused:
+            raise RuntimeError("Mosaic refused the kernel")
+        return 1.0 + ranked.index(cand) * 0.01
+
+    res = tuning.search_kernel(key, measure=measure, persist=False)
+    assert res.dropped == 1 and (refused, None) in res.trace
+    assert res.config == ranked[1]
+
+
 def test_search_respects_snr_gate_without_timing_gated_configs():
     key = tuning.TuneKey.kernel(256, 1)
     space = tuning.candidates(256, precisions=("f32", "bs16"))
@@ -397,16 +414,46 @@ def test_search_respects_snr_gate_without_timing_gated_configs():
     assert res.config.precision == "f32"
 
 
-def test_measured_search_drops_raising_candidates():
+def test_measured_search_drops_raising_candidates(caplog):
+    """A candidate whose measure raises is dropped, never silently: it
+    is in the trace, at whatever rung it raised, and logged with its error."""
     def measure(cand, iters):
-        if cand == "bad":
-            raise RuntimeError("infeasible at trace time")
-        return {"a": 3.0, "b": 1.0}[cand]
+        if cand == "bad" or (cand == "late" and iters > 1):
+            raise RuntimeError(f"infeasible at {iters} iters")
+        return {"a": 3.0, "b": 1.0, "late": 0.5}[cand]
 
-    best, t, trace = tuning.measured_search(["bad", "a", "b"], measure,
-                                            rungs=(1,))
+    with caplog.at_level("WARNING", logger="repro.tuning.search"):
+        best, t, trace = tuning.measured_search(["bad", "a", "b", "late"],
+                                                measure, rungs=(1, 3))
     assert best == "b" and t == 1.0
-    assert ("bad", None) in trace
+    assert ("bad", None) in trace and ("late", None) in trace
+    assert sum(s is None for _, s in trace) == 2
+    assert "infeasible at 1 iters" in caplog.text
+    assert "infeasible at 3 iters" in caplog.text
+
+
+def test_device_fingerprint_raises_without_a_device(monkeypatch):
+    """No device is an error, never a cache key named 'unknown'."""
+    import jax
+
+    def no_devices():
+        raise RuntimeError("no backend")
+
+    monkeypatch.setattr(jax, "devices", no_devices)
+    with pytest.raises(RuntimeError, match="no backend"):
+        tuning.space.device_fingerprint()
+
+
+def test_device_table_rows():
+    """The v5e row carries the published peaks; an unlisted kind raises."""
+    v5e = cost.device_spec("TPU v5 lite")
+    assert (v5e.peak_hbm_bytes, v5e.hbm_bytes) == (819e9, 16 * 10**9)
+    assert v5e.peak_matmul_flops == 197e12 / 6
+    assert v5e.line_block % 128 == 0 and not v5e.f16_operands
+    assert "197 TFLOP/s" in v5e.source
+    assert cost.device_spec().kind == "cpu"      # the test host
+    with pytest.raises(KeyError, match="TPU v99"):
+        cost.device_spec("TPU v99")
 
 
 # ---------------------------------------------------------------------------
