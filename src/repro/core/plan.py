@@ -85,12 +85,19 @@ BACKEND_XLA = "xla"         # one jnp op per atom (the unfused oracle)
 FUSE_MEGA = "mega"
 
 
+# Kernels take complex scenes as two f32 planes. The conversions each way
+# run under their own scope, so a profile tells this glue apart from the
+# kernels wherever it is called (steps, strips, the sharded lowering).
+
 def split(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    return jnp.real(x).astype(jnp.float32), jnp.imag(x).astype(jnp.float32)
+    with jax.named_scope("split"):
+        return (jnp.real(x).astype(jnp.float32),
+                jnp.imag(x).astype(jnp.float32))
 
 
 def unsplit(xr: jnp.ndarray, xi: jnp.ndarray) -> jnp.ndarray:
-    return xr.astype(jnp.complex64) + 1j * xi.astype(jnp.complex64)
+    with jax.named_scope("unsplit"):
+        return xr.astype(jnp.complex64) + 1j * xi.astype(jnp.complex64)
 
 
 # ---------------------------------------------------------------------------
@@ -555,17 +562,24 @@ class Pipeline:
         """
         x = raw
         for s in self.steps:
-            x = s.fn(x)
+            with jax.named_scope(s.name):
+                x = s.fn(x)
         return x
 
     def jitted(self) -> Callable[[jnp.ndarray], jnp.ndarray]:
         """One jax.jit callable for the whole step sequence. Retraces per
         distinct input shape (each batch size B is one trace); the
-        focusing service pre-traces its micro-batch sizes at warm-up."""
-        @jax.jit
+        focusing service pre-traces its micro-batch sizes at warm-up.
+
+        The function is named ``focus_<pipeline name>`` (HLO module
+        ``jit_focus_<name>``) and each step's operations carry the step's
+        name as their scope, so a profile attributes each device
+        operation to a plan step; the compiler's own split of the
+        complex argument where it enters carries the argument's name."""
         def f(raw):
             return self.run(raw)
-        return f
+        f.__name__ = f.__qualname__ = f"focus_{self.name}"
+        return jax.jit(f)
 
     def lower_sharded(self, mesh, axes=("data",), **kw):
         """Lower this compiled pipeline onto a device mesh: every

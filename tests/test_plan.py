@@ -238,6 +238,30 @@ def test_filter_cache_skips_host_math_on_recompile():
     assert after["misses"] == before["misses"]
 
 
+def test_jitted_pipeline_names_its_module_steps_and_glue():
+    """The jitted fused3 pipeline lowers to module ``jit_focus_fused3``,
+    and every operation of its body carries its plan step's scope; inside
+    each step, the complex <-> f32-plane conversions carry ``split`` and
+    ``unsplit``. A device profile attributes operations by these names."""
+    import re
+
+    import jax
+
+    pipe = build_pipeline(make_test_scene(128), "fused3")
+    steps = [s.name for s in pipe.steps]
+    assert steps == ["azimuth_fft", "range_comp_rcmc", "azimuth_compression"]
+    x = jax.ShapeDtypeStruct((2, 128, 128), jnp.complex64)
+    text = pipe.jitted().lower(x).as_text(debug_info=True)
+    assert re.search(r"^module @jit_focus_fused3\b", text, re.M)
+    names = set(re.findall(r'loc\("(jit\(focus_fused3\)/[^"]*)"', text))
+    scopes = {n.split("/")[1] for n in names}
+    assert scopes == set(steps)
+    for step in steps:
+        for glue in ("split", "unsplit"):
+            assert any(n.startswith(f"jit(focus_fused3)/{step}/{glue}/")
+                       for n in names), (step, glue)
+
+
 def test_unknown_filter_and_variant_raise():
     bad = SpectralPlan("p", (Stage("a", axis=1, fwd=True,
                                    filters=("nope",)),))
