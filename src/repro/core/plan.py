@@ -67,6 +67,8 @@ from repro.kernels.fft4step import (
     FILTER_SHARED,
     FILTER_SHARED_OUTER,
     default_line_block as _line_block,
+    dispatch_scope,
+    resolve_factors,
     resolve_precision,
 )
 from repro.kernels.transpose import transpose as tiled_transpose
@@ -511,6 +513,9 @@ class Step:
     # This is what lets lower_sharded split the in-kernel segment chain at
     # corner-turn boundaries and re-shard each group's filters per device.
     seg_filter_args: Optional[tuple] = None
+    # (physical axis, length, factors) of the transform a per-axis Pallas
+    # dispatch runs; None for any other step
+    transform: Optional[tuple] = None
 
 
 @dataclasses.dataclass
@@ -549,6 +554,15 @@ class Pipeline:
     @property
     def hbm_roundtrips(self) -> int:
         return sum(s.hbm_roundtrips for s in self.steps)
+
+    def spectral_dispatches(self) -> list[tuple]:
+        """``(step name, physical axis, length, factors)`` of each
+        per-axis spectral dispatch that transforms, in order (a fused1
+        megakernel runs its segments in one dispatch and lists none).
+        Each runs under a scope inside its step's that names its length
+        and split (``range_comp_rcmc/dft6144_48x128``)."""
+        return [(s.name, *s.transform) for s in self.steps
+                if s.transform]
 
     def run(self, raw: jnp.ndarray) -> jnp.ndarray:
         """Execute the compiled steps on one scene ``(na, nr)`` or a
@@ -759,11 +773,17 @@ def _make_spectral_step(group, mode, arrays, *, cfg, transposed, backend,
         n3=tuned.n3, karatsuba=bool(tuned.karatsuba),
     )
     filter_kw = _payload_to_device(mode, arrays, axis, transposed)
+    transform = None
+    scope = f"mul{n}"
+    if backend == BACKEND_PALLAS and (fwd or inv):
+        transform = (phys_axis, n, _transform_factors(n, kernel_kw))
+        scope = dispatch_scope(n, transform[2])
 
     if backend == BACKEND_PALLAS:
         def fn(x, _fk=filter_kw):
             xr, xi = split(x)
-            yr, yi = ops.spectral_op(xr, xi, **_fk, **kernel_kw)
+            with jax.named_scope(scope):
+                yr, yi = ops.spectral_op(xr, xi, **_fk, **kernel_kw)
             return unsplit(yr, yi)
     else:
         # the unfused oracle: same math, one jnp op per piece
@@ -781,14 +801,26 @@ def _make_spectral_step(group, mode, arrays, *, cfg, transposed, backend,
             fk = _slice_filter_kwargs(_fk, mode, phys_axis, lo, hi)
             if backend == BACKEND_PALLAS:
                 xr, xi = split(xs)
-                yr, yi = ops.spectral_op(xr, xi, **fk, **kernel_kw)
+                with jax.named_scope(scope):
+                    yr, yi = ops.spectral_op(xr, xi, **fk, **kernel_kw)
                 return unsplit(yr, yi)
             return _xla_apply(xs, fwd, inv, mode, fk, phys_axis)
 
     fused = backend == BACKEND_PALLAS and len(group) > 1
     return Step(name, fn, 1, 1, fused, stream_axis, strip_fn,
                 kind="spectral", phys_axis=phys_axis, filter_mode=mode,
-                filter_kw=filter_kw, kernel_kw=kernel_kw)
+                filter_kw=filter_kw, kernel_kw=kernel_kw,
+                transform=transform)
+
+
+def _transform_factors(n: int, kernel_kw: dict) -> tuple:
+    """The split a kernel runs for a length-n transform under
+    ``kernel_kw``'s factor knobs; () for the Stockham baseline, which
+    runs no DFT matmuls."""
+    if kernel_kw["fft_impl"] != "matmul":
+        return ()
+    return resolve_factors(n, kernel_kw["n1"], kernel_kw["n2"],
+                           kernel_kw["n3"])
 
 
 def _seg_device_args(mode: str, arrays: tuple) -> list:

@@ -40,14 +40,26 @@ expanded and squeezed transparently).
 Mixed-radix factorization rules
 -------------------------------
 ``SpectralSpec.factors()`` returns a two- OR three-factor decomposition
-``n = n1*n2[*n3]`` with every factor a power of two <= 128 (the MXU edge):
+``n = n1*n2[*n3]`` with every factor <= 128 (the MXU edge); a DFT stage
+is a dense matmul, so any radix up to 128 runs, not only powers of two:
 
-  * n <= 16384: the ~sqrt two-factor split (n1 >= n2), e.g. 4096 = 64*64,
-    8192 = 128*64, 512 = 32*16.
-  * 16384 < n <= 2^21: a three-factor split, e.g. 32768 = 32*32*32 —
-    the four-step formulation applies recursively (stage-A matmul,
-    twiddle, then a four-step FFT of the remaining length), so lengths
-    beyond 128*128 still map onto dense MXU matmuls instead of erroring.
+  * powers of two, n <= 16384: the ~sqrt two-factor split (n1 >= n2),
+    e.g. 4096 = 64*64, 8192 = 128*64, 512 = 32*16;
+  * powers of two, 16384 < n <= 2^21: a three-factor split, e.g.
+    32768 = 32*32*32 — the four-step formulation applies recursively
+    (stage-A matmul, twiddle, then a four-step FFT of the remaining
+    length), so lengths beyond 128*128 still map onto dense MXU matmuls;
+  * other lengths (a range line of 5616 samples, or the 6144-point
+    transform that holds it): two factors up to 128*128, three past it.
+    A split whose innermost factor is 128 and whose others are multiples
+    of 8 comes first (6144 = 48*128): every reshape and leading-dim swap
+    of the transform then stays on whole (8, 128) tiles. Else a split
+    of multiples of 8 (384 = 24*16), else the most even one
+    (96 = 12*8, 5616 = 78*72; Mosaic pads the partial tiles).
+  * a length with no such split (2*131, or past 128^3) raises a
+    ValueError naming it; nothing pads to a longer transform. The
+    Stockham baseline and the float16-operand tiers (f16, bs16) run
+    powers of two only and refuse other lengths (:func:`check_length`).
 
 Explicit ``n1``/``n2``/``n3`` override the default (the repro.tuning
 subsystem sweeps them per (B, n) together with ``block``, ``karatsuba``
@@ -86,6 +98,7 @@ FILTER_SHARED_OUTER = "shared_outer"  # H[sample] * exp(i sum_k u v): range
 
 
 MAX_FACTOR = 128  # MXU edge: every DFT matmul factor must be <= 128
+SUBLANES = 8      # rows of a TPU vreg tile: a compiled split's factor unit
 
 # Residency modes of the single-dispatch 2-D megakernel (build_mega_call).
 RESIDENT_VMEM = "vmem"      # whole (Bb, na, nr) slab on-chip per grid step
@@ -141,6 +154,24 @@ def check_precision(precision, interpret: bool) -> None:
             f"precision {prec.name!r} needs float16 matmul operands, which "
             f"{spec.kind} does not support; use one of "
             f"{sorted(p for p, v in PRECISIONS.items() if v.dtype != 'float16')}")
+
+
+def check_length(spec) -> None:
+    """Refuse a transform whose length the chosen implementation cannot
+    run: the Stockham baseline (radix 4 and 2) and the float16-operand
+    tiers (f16, bs16) run powers of two only. The DFT-as-matmul kernels
+    at f32 and bf16 take any length :func:`default_factorization` can
+    split."""
+    if not (spec.fwd or spec.inv) or _is_pow2(spec.n):
+        return
+    if spec.fft_impl == "stockham":
+        raise ValueError(
+            f"fft_impl 'stockham' runs power-of-two lengths only, got "
+            f"n={spec.n}; use fft_impl 'matmul'")
+    if PRECISIONS[spec.precision].dtype == "float16":
+        raise ValueError(
+            f"precision {spec.precision!r} runs power-of-two lengths only, "
+            f"got n={spec.n}; use 'f32' or 'bf16'")
 
 
 _DEFAULT_SCOPED_VMEM = 16 * 2**20     # Mosaic's limit when none is set
@@ -226,26 +257,105 @@ def resolve_precision(p) -> Precision:
             f"unknown precision {p!r}; one of {sorted(PRECISIONS)}") from None
 
 
-def default_factorization(n: int) -> tuple[int, ...]:
-    """Mixed-radix split of n into 2 or 3 power-of-two factors, each <= 128.
+def _is_pow2(n: int) -> bool:
+    return n >= 1 and not n & (n - 1)
 
-    n <= 128*128:  the ~sqrt two-factor split with n1 >= n2 (the paper's
-                   regime: 4096 = 64*64; plus 8192 = 128*64, 512 = 32*16).
-    n <= 128^3:    three factors f1 >= f2 >= f3 (e.g. 32768 = 32*32*32) —
-                   the four-step recursion keeps every stage on the MXU.
-    """
-    if n & (n - 1):
-        raise ValueError(f"FFT length must be a power of two, got {n}")
+
+def _pow2_factorization(n: int) -> tuple[int, ...]:
+    """The power-of-two rule: the ~sqrt split, n1 >= n2, then three
+    factors f1 >= f2 >= f3 past 128*128."""
     p = n.bit_length() - 1
     if n <= MAX_FACTOR * MAX_FACTOR:
         n1 = 1 << ((p + 1) // 2)
         return n1, n // n1
-    if n > MAX_FACTOR ** 3:
-        raise ValueError(
-            f"n={n} exceeds the three-factor limit {MAX_FACTOR ** 3}")
     p1 = (p + 2) // 3
     p2 = (p - p1 + 1) // 2
     return 1 << p1, 1 << p2, 1 << (p - p1 - p2)
+
+
+def splits(n: int, k: int) -> list[tuple[int, ...]]:
+    """Every ordered split of n into k factors, each between 1 and
+    MAX_FACTOR (n = f1 * ... * fk)."""
+    if k == 1:
+        return [(n,)] if 1 <= n <= MAX_FACTOR else []
+    return [(f,) + rest for f in range(1, MAX_FACTOR + 1) if n % f == 0
+            for rest in splits(n // f, k - 1)]
+
+
+def _tiled(fs: Sequence[int]) -> bool:
+    """Every factor a multiple of the 8-row sublane tile: each reshape
+    and leading-dim swap of the four-step recursion on an (n, lines) slab
+    then stays on whole (8, 128) tiles. Other splits compile for a v5e
+    too (5616 = 78 * 72), with their partial tiles padded."""
+    return all(f % SUBLANES == 0 for f in fs)
+
+
+def _preference(fs: tuple) -> tuple:
+    """Sort key of a non-power-of-two split: the 128-point factor innermost
+    with the rest on whole sublane tiles (6144 = 48 * 128), then any tiled
+    split, then the most even split (least sum), larger factors first."""
+    lane_whole = fs[-1] == MAX_FACTOR and _tiled(fs[:-1])
+    return (not lane_whole, not _tiled(fs), sum(fs), tuple(-f for f in fs))
+
+
+def default_factorization(n: int) -> tuple[int, ...]:
+    """Mixed-radix split of n into 2 factors (3 past 128*128), each <= 128.
+
+    Powers of two:  the ~sqrt two-factor split with n1 >= n2 (the paper's
+                    regime: 4096 = 64*64; plus 8192 = 128*64, 512 = 32*16),
+                    then three factors f1 >= f2 >= f3 (32768 = 32*32*32).
+    Other lengths:  a split whose innermost factor is 128 and whose others
+                    are multiples of 8 where one exists (6144 = 48*128),
+                    else one of multiples of 8 (384 = 24*16), else the most
+                    even split (96 = 12*8, 5616 = 78*72).
+
+    A length with no split into factors <= 128 (2*131, or past 128^3)
+    raises a ValueError naming it; nothing pads to a longer transform.
+    """
+    if n < 1:
+        raise ValueError(f"FFT length must be positive, got {n}")
+    if n > MAX_FACTOR ** 3:
+        raise ValueError(
+            f"n={n} exceeds the three-factor limit {MAX_FACTOR ** 3}")
+    if _is_pow2(n):
+        return _pow2_factorization(n)
+    fs = splits(n, 2 if n <= MAX_FACTOR * MAX_FACTOR else 3)
+    if not fs:
+        raise ValueError(
+            f"FFT length {n} has no split into factors <= {MAX_FACTOR}")
+    return min(fs, key=_preference)
+
+
+def resolve_factors(n: int, n1: Optional[int] = None,
+                    n2: Optional[int] = None,
+                    n3: Optional[int] = None) -> tuple[int, ...]:
+    """The split a transform of length n runs: the pinned ``n1/n2/n3``
+    (``n2`` defaults to n // n1), else :func:`default_factorization`.
+    A split that does not multiply to n, or a factor outside
+    [1, MAX_FACTOR], raises a ValueError."""
+    if n1 is None:
+        return default_factorization(n)
+    fs = [f for f in (n1, n2, n3) if f is not None]
+    if len(fs) == 1:
+        fs.append(n // n1)
+    fs = tuple(int(f) for f in fs)
+    if math.prod(fs) != n:
+        raise ValueError(f"factors {fs} do not multiply to n={n}")
+    for f in fs:
+        if f < 1 or f > MAX_FACTOR:
+            raise ValueError(
+                f"factor {f} is not between 1 and {MAX_FACTOR} (the MXU "
+                f"edge): {fs}")
+    return fs
+
+
+def dispatch_scope(n: int, factors: Sequence[int]) -> str:
+    """The name of one transform in a profile: its length and split
+    (``dft6144_48x128``), or ``fft<n>`` where it runs no DFT matmuls
+    (the Stockham baseline)."""
+    if not factors:
+        return f"fft{n}"
+    return f"dft{n}_" + "x".join(str(f) for f in factors)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -270,27 +380,8 @@ class SpectralSpec:
 
     def factors(self) -> tuple[int, ...]:
         """The mixed-radix decomposition n = n1 * n2 [* n3], every factor
-        a power of two <= 128 (see the module docstring for the rules)."""
-        if self.n1 is not None:
-            fs = [self.n1]
-            if self.n2 is not None:
-                fs.append(self.n2)
-            if self.n3 is not None:
-                fs.append(self.n3)
-            if len(fs) == 1:
-                fs.append(self.n // self.n1)
-            fs = tuple(fs)
-        else:
-            fs = default_factorization(self.n)
-        if int(np.prod(fs)) != self.n:
-            raise ValueError(f"factors {fs} do not multiply to n={self.n}")
-        for f in fs:
-            if f < 1 or f & (f - 1):
-                raise ValueError(f"factor {f} is not a power of two: {fs}")
-            if f > MAX_FACTOR:
-                raise ValueError(
-                    f"factor {f} exceeds the MXU edge {MAX_FACTOR}: {fs}")
-        return fs
+        between 1 and 128 (see the module docstring for the rules)."""
+        return resolve_factors(self.n, self.n1, self.n2, self.n3)
 
     @property
     def num_dft_consts(self) -> int:
@@ -758,6 +849,7 @@ def build_spectral_call(spec: SpectralSpec, lines: int, batch: int = 1,
     if config is not None:
         spec = config.apply(spec)
     check_precision(spec.precision, interpret)
+    check_length(spec)
     n = spec.n
     L = spec.block
     if lines % L:
@@ -1312,6 +1404,8 @@ def build_mega_call(spec: MegaSpec, batch: int = 1,
       :func:`_mega_kernel_staged`).
     """
     check_precision(spec.precision, interpret)
+    for seg in spec.segments:
+        check_length(spec.seg_spec(seg))
     na, nr = spec.na, spec.nr
     const_plan = _mega_const_plan(spec)
     const_arrays = [jnp.asarray(c) for _, cs in const_plan for c in cs]

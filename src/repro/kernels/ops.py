@@ -98,7 +98,9 @@ def spectral_op(
       outer:  u (lines,) or (lines, K), v (N,) or (N, K) —
               filter = exp(i * sum_k u[line,k] * v[sample,k])
     n1/n2/n3: optional mixed-radix factorization override (n = n1*n2[*n3],
-    powers of two <= 128); default per fft4step.default_factorization.
+    every factor <= 128, any radix); default per
+    fft4step.default_factorization (any length with such a split: 6144 =
+    48*128; powers of two keep the ~sqrt split, 4096 = 64*64).
     precision: matmul-operand Precision policy name (fft4step.PRECISIONS:
     f32 | bf16 | f16 | bs16 block-scaled f16). `compute_dtype` is the
     deprecated pre-policy spelling of the same knob.

@@ -144,6 +144,11 @@ def _factors(config: KernelConfig, n: int) -> tuple:
     return config.factors() or default_factorization(n)
 
 
+def _legal_split(n: int, fs: tuple) -> bool:
+    """``fs`` multiplies to n and every factor lies in [1, MAX_FACTOR]."""
+    return math.prod(fs) == n and all(1 <= f <= MAX_FACTOR for f in fs)
+
+
 def _const_bytes(factors: tuple) -> int:
     """DFT matrices + inter-stage twiddles, split re/im float32 — the
     broadcast operands every grid step re-loads."""
@@ -170,10 +175,7 @@ def vmem_bytes(config: KernelConfig, key: TuneKey) -> int:
 def structurally_feasible(config: KernelConfig, key: TuneKey) -> bool:
     """Shape legality: the config can build a kernel for ``key`` at all."""
     n = key.n
-    fs = _factors(config, n)
-    if math.prod(fs) != n:
-        return False
-    if any(f > MAX_FACTOR or f & (f - 1) for f in fs):
+    if not _legal_split(n, _factors(config, n)):
         return False
     block = config.block or device_spec().line_block
     # ops.spectral_op PADS lines up to a block multiple, so a block that
@@ -481,9 +483,7 @@ def schedule_structurally_feasible(schedule: Schedule,
     for i, shape in enumerate(problem.segments):
         n = problem.seg_n(shape)
         fs = schedule.segment(i).factors() or default_factorization(n)
-        if math.prod(fs) != n:
-            return False
-        if any(f > MAX_FACTOR or f & (f - 1) for f in fs):
+        if not _legal_split(n, fs):
             return False
     if not problem.mega:
         block = schedule.block or device_spec().line_block

@@ -39,6 +39,7 @@ from repro.kernels.fft4step import (
     SpectralSpec,
     default_factorization,
     resolve_precision,
+    splits,
 )
 
 KIND_KERNEL = "kernel"       # one fused spectral dispatch (rows, fwd+inv)
@@ -53,6 +54,16 @@ CONFIG_KEYS = SPECTRAL_KEYS + ("col_block",) + MEGA_KEYS
 # the per-segment scheduling decisions a Schedule can vary where a flat
 # KernelConfig holds one global value
 SEGMENT_KEYS = ("n1", "n2", "n3", "karatsuba")
+
+
+def _check_factor_knobs(cfg) -> None:
+    """Each pinned factor is a whole number between 1 and MAX_FACTOR
+    (any radix the DFT-as-matmul kernels run, not only powers of two)."""
+    for name in ("n1", "n2", "n3"):
+        f = getattr(cfg, name)
+        if f is not None and not 1 <= f <= MAX_FACTOR:
+            raise ValueError(
+                f"{name}={f} is not a factor between 1 and {MAX_FACTOR}")
 
 
 def bucket_batch(b: int) -> int:
@@ -167,11 +178,7 @@ class KernelConfig:
     def __post_init__(self):
         if self.precision is not None:
             resolve_precision(self.precision)   # raises on unknown policy
-        for name in ("n1", "n2", "n3"):
-            f = getattr(self, name)
-            if f is not None and (f < 1 or f & (f - 1) or f > MAX_FACTOR):
-                raise ValueError(
-                    f"{name}={f} is not a power of two <= {MAX_FACTOR}")
+        _check_factor_knobs(self)
         if self.residency not in (None, RESIDENT_VMEM, RESIDENT_STAGED):
             raise ValueError(
                 f"residency={self.residency!r} is not one of "
@@ -266,11 +273,7 @@ class SegmentConfig:
     karatsuba: Optional[bool] = None     # tri-state, like KernelConfig
 
     def __post_init__(self):
-        for name in ("n1", "n2", "n3"):
-            f = getattr(self, name)
-            if f is not None and (f < 1 or f & (f - 1) or f > MAX_FACTOR):
-                raise ValueError(
-                    f"{name}={f} is not a power of two <= {MAX_FACTOR}")
+        _check_factor_knobs(self)
 
     def factors(self) -> Optional[tuple]:
         """The explicit factorization (n1, n2[, n3]), or None if deferred."""
@@ -468,26 +471,22 @@ class ScheduleProblem:
 
 def factorizations(n: int) -> list[tuple[int, ...]]:
     """Candidate mixed-radix splits of ``n``: every sorted-descending
-    2-factor decomposition into powers of two <= MAX_FACTOR, switching to
-    3-factor decompositions past MAX_FACTOR**2 (the four-step recursion's
-    3-stage regime). Invariants (tested): factors sorted descending, every
-    factor <= MAX_FACTOR, product == n, non-empty up to MAX_FACTOR**3."""
-    if n < 2 or n & (n - 1):
-        raise ValueError(f"FFT length must be a power of two >= 2, got {n}")
-    p = n.bit_length() - 1
-    out: list[tuple[int, ...]] = []
-    if n <= MAX_FACTOR * MAX_FACTOR:
-        for p1 in range((p + 1) // 2, p + 1):
-            n1, n2 = 1 << p1, 1 << (p - p1)
-            if n1 <= MAX_FACTOR and n1 >= n2 >= 1:
-                out.append((n1, n2))
-    else:
-        for p1 in range(1, p - 1):
-            for p2 in range(1, p - p1):
-                fs = (1 << p1, 1 << p2, 1 << (p - p1 - p2))
-                if all(f <= MAX_FACTOR for f in fs) and fs[0] >= fs[1] >= fs[2]:
-                    out.append(fs)
-    return out or [default_factorization(n)]
+    2-factor decomposition into factors <= MAX_FACTOR (powers of two when
+    ``n`` is one, any radix otherwise), switching to 3-factor
+    decompositions past MAX_FACTOR**2 (the four-step recursion's 3-stage
+    regime); :func:`default_factorization`'s split goes first where it
+    is not sorted (6144 = 48*128). Invariants (tested): every factor
+    <= MAX_FACTOR, product == n, non-empty for every length that has a
+    split; a length with none raises a ValueError naming it."""
+    if n < 2:
+        raise ValueError(f"FFT length must be >= 2, got {n}")
+    default = default_factorization(n)         # raises where none exists
+    k = 2 if n <= MAX_FACTOR * MAX_FACTOR else 3
+    out = [fs for fs in splits(n, k)
+           if list(fs) == sorted(fs, reverse=True)]
+    if default not in out:
+        out.insert(0, default)
+    return out
 
 
 def candidates(n: int, blocks=(4, 8, 16),

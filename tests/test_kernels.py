@@ -91,6 +91,87 @@ def test_factorizations(n1, n2):
     assert_close(got, ref.fft_ref(xr, xi, axis=1))
 
 
+def _pow2_split_before_mixed_radix(n):
+    """The power-of-two rule as it stood before other lengths were
+    allowed: the ~sqrt split n1 >= n2, three factors past 128 * 128."""
+    p = n.bit_length() - 1
+    if n <= 128 * 128:
+        n1 = 1 << ((p + 1) // 2)
+        return n1, n // n1
+    p1 = (p + 2) // 3
+    p2 = (p - p1 + 1) // 2
+    return 1 << p1, 1 << p2, 1 << (p - p1 - p2)
+
+
+def test_power_of_two_splits_unchanged_up_to_2_21():
+    from repro.kernels.fft4step import default_factorization
+    for p in range(22):
+        assert default_factorization(1 << p) == \
+            _pow2_split_before_mixed_radix(1 << p), 1 << p
+    assert default_factorization(4096) == (64, 64)
+    assert default_factorization(8192) == (128, 64)
+    assert default_factorization(32768) == (32, 32, 32)
+
+
+@pytest.mark.parametrize("n,want", [(6144, (48, 128)), (384, (24, 16)),
+                                    (96, (12, 8)), (5616, (78, 72)),
+                                    (7, (7, 1)), (3 * 2 ** 14, (24, 16, 128))])
+def test_non_power_of_two_default_split(n, want):
+    """The 128-point factor innermost over whole sublane tiles where such
+    a split exists, else tiled factors, else the most even split."""
+    from repro.kernels.fft4step import default_factorization
+    assert default_factorization(n) == want
+
+
+@pytest.mark.parametrize("n", [2 * 131, 128 ** 3 + 1, 3 * 128 ** 3])
+def test_unfactorable_length_raises_naming_it(n):
+    from repro.kernels.fft4step import SpectralSpec, default_factorization
+    with pytest.raises(ValueError, match=str(n)):
+        default_factorization(n)
+    with pytest.raises(ValueError, match=str(n)):
+        SpectralSpec(n=n, fwd=True, inv=False, filter_mode="none").factors()
+
+
+def test_factor_past_the_mxu_edge_raises():
+    from repro.kernels.fft4step import SpectralSpec
+    with pytest.raises(ValueError, match="between 1 and 128"):
+        SpectralSpec(n=6144, fwd=True, inv=False, filter_mode="none",
+                     n1=24, n2=256).factors()
+    assert SpectralSpec(n=6144, fwd=True, inv=False, filter_mode="none",
+                        n1=96).factors() == (96, 64)
+
+
+@pytest.mark.parametrize("n", [96, 384, 6144])
+@pytest.mark.parametrize("axis", [0, 1])
+def test_non_power_of_two_lengths_match_jnp_fft(n, axis):
+    """spectral_axis0/1 at mixed-radix lengths: the forward FFT against
+    jnp.fft, and the fused FFT * filter * IFFT against the oracle."""
+    lines = 8
+    shape = (lines, n) if axis == 1 else (n, lines)
+    xr, xi = rand(*shape), rand(*shape)
+    ax = 1 if axis == 1 else 0
+    got = ops.spectral_op(jnp.asarray(xr), jnp.asarray(xi), axis=axis,
+                          fwd=True, inv=False, block=8)
+    want = np.fft.fft(xr + 1j * xi, axis=ax)
+    assert_close(got, (want.real, want.imag))
+    hr, hi = rand(n), rand(n)
+    got = ops.spectral_op(jnp.asarray(xr), jnp.asarray(xi),
+                          hr=jnp.asarray(hr), hi=jnp.asarray(hi), axis=axis,
+                          filter_mode="shared", block=8)
+    h = (hr + 1j * hi)[None, :] if axis == 1 else (hr + 1j * hi)[:, None]
+    want = np.fft.ifft(np.fft.fft(xr + 1j * xi, axis=ax) * h, axis=ax)
+    assert_close(got, (want.real, want.imag))
+
+
+@pytest.mark.parametrize("kw", [dict(fft_impl="stockham"),
+                                dict(precision="f16"),
+                                dict(precision="bs16")])
+def test_power_of_two_only_paths_refuse_other_lengths(kw):
+    xr = jnp.asarray(rand(8, 96))
+    with pytest.raises(ValueError, match="power-of-two lengths only"):
+        ops.spectral_op(xr, xr, axis=1, fwd=True, inv=False, block=8, **kw)
+
+
 def test_karatsuba_and_bf16():
     xr, xi = rand(4, 512), rand(4, 512)
     want = ref.fft_ref(xr, xi, axis=1)

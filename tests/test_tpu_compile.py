@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.sar import build_pipeline, paper_scene
+from repro.core.sar import SceneConfig, build_pipeline, paper_scene
 from repro.kernels import fft4step, ops
 from repro.tuning import cost
 
@@ -80,6 +80,26 @@ def test_fused3_dispatch_compiles_for_v5e(on_v5e, one_chip, paper_cfg, step,
                           interpret=False, tune="off")
     assert pipe.steps[step].kernel_kw["block"] % 128 == 0
     x = jax.ShapeDtypeStruct((1, N, N), jnp.complex64, sharding=one_chip)
+    _compile(pipe.steps[step].fn, x)
+
+
+# the ERS-1/2 image-mode block (sarbench/configs/ers_cband_8192.json) cut
+# to 256 lines: its range steps run the 6144-point transform as 48 x 128
+ERS_LINES = SceneConfig(na=256, nr=6144, fc=5.3e9, bandwidth=15.55e6,
+                        tp=37.12e-6, fs=18.96e6, prf=1679.9, v=7100.0,
+                        r0=850e3, aperture_time=0.1, noise_db=20.0)
+
+
+@pytest.mark.parametrize("step", [0, 1, 2],
+                         ids=["azimuth_fft", "range_shared_outer",
+                              "azimuth_compression_outer"])
+def test_fused3_mixed_radix_dispatch_compiles_for_v5e(on_v5e, one_chip,
+                                                      step):
+    pipe = build_pipeline(ERS_LINES, "fused3", precision="f32",
+                          interpret=False, tune="off")
+    assert pipe.spectral_dispatches()[1][2:] == (6144, (48, 128))
+    x = jax.ShapeDtypeStruct((1, ERS_LINES.na, ERS_LINES.nr), jnp.complex64,
+                             sharding=one_chip)
     _compile(pipe.steps[step].fn, x)
 
 

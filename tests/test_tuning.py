@@ -130,8 +130,13 @@ def test_kernel_config_from_dict_tolerates_legacy_extras():
               "karatsuba": False, "precision": None, "seconds": 0.01}
     c = tuning.KernelConfig.from_dict(legacy)
     assert (c.block, c.factors()) == (16, (32, 16))
-    with pytest.raises(ValueError, match="power of two"):
-        tuning.KernelConfig(n1=96)
+    # any radix up to the MXU edge is a factor (6144 = 48 * 128); past it
+    # no matmul stage can run one
+    assert tuning.KernelConfig(n1=96, n2=64).factors() == (96, 64)
+    with pytest.raises(ValueError, match="between 1 and 128"):
+        tuning.KernelConfig(n1=MAX_FACTOR + 1)
+    with pytest.raises(ValueError, match="between 1 and 128"):
+        tuning.SegmentConfig(n1=256)
     with pytest.raises(ValueError, match="precision"):
         tuning.KernelConfig(precision="f8")
 
@@ -193,8 +198,17 @@ def test_factorizations_invariants_up_to_2_21():
 
 
 def test_factorizations_rejects_non_power_of_two():
-    with pytest.raises(ValueError):
-        tuning.factorizations(96)
+    """Non-powers of two are split like any length, led by the library
+    default; a length with no split into factors <= 128 raises and is
+    named, never padded to a longer transform."""
+    fs = tuning.factorizations(6144)
+    assert fs[0] == default_factorization(6144) == (48, 128)
+    assert set(fs) == {(48, 128), (128, 48), (96, 64)}
+    assert all(math.prod(f) == 96 and max(f) <= MAX_FACTOR
+               for f in tuning.factorizations(96))
+    for n in (2 * 131, 3 * MAX_FACTOR ** 3):
+        with pytest.raises(ValueError, match=str(n)):
+            tuning.factorizations(n)
 
 
 # ---------------------------------------------------------------------------
