@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import resource
 import sys
 import time
 from typing import Callable, Optional
@@ -27,6 +28,8 @@ from sarbench.spec import Cell
 
 # calls whose images are compared, drawn from the seed
 SAMPLE_CALLS = 3
+# calls a traced window holds at most: the profile grows with every call
+TRACED_CALLS = 16
 # the compared error of an image holding NaN or Inf (JSON has no Inf)
 NON_FINITE = 1e30
 
@@ -66,6 +69,7 @@ class Window:
     """What the window measured, for the end-to-end and per-layer readers."""
 
     seconds: float                      # length of the measured window
+    calls: int                          # calls made in the window
     attempted: int
     failed: int
     scenes: int                         # images that reached the host
@@ -82,7 +86,7 @@ class Run:
     scene: scenelib.Scene
     peak: dict
     window: Window
-    trace: object = None                # trace.Trace of the window, or None
+    trace: object = None                # scopes.ScopedTrace, or None
 
     @property
     def spans(self) -> Spans:
@@ -159,20 +163,24 @@ class ClosedLoop:
         np.asarray(jax.block_until_ready(
             self.entry(jax.device_put(self.host_batches[0]))))
 
-    def run(self, seconds: float, counter: CompileCounter) -> Window:
+    def run(self, seconds: float, counter: CompileCounter,
+            max_calls: Optional[int] = None) -> Window:
+        """Measure until ``seconds`` have passed or, where given,
+        ``max_calls`` calls have been made, whichever comes first."""
         rng = np.random.default_rng([self.seed, 1])
         spans = Spans()
         c0 = counter.count
         with spans("window"):
             t_start = time.perf_counter()
-            calls = self._calls(seconds, spans, rng)
+            calls = self._calls(seconds, spans, rng, max_calls)
         window = spans.items[-1].t1 - t_start
         scenes = calls * self.batch
-        return Window(window, scenes, 0, scenes,
+        return Window(window, calls, scenes, 0, scenes,
                       {"scenes_per_s": scenes / window}, spans,
                       compiles=counter.count - c0)
 
-    def _calls(self, seconds: float, spans: Spans, rng) -> int:
+    def _calls(self, seconds: float, spans: Spans, rng,
+               max_calls: Optional[int]) -> int:
         import jax
 
         n_hb = len(self.host_batches)
@@ -195,7 +203,7 @@ class ClosedLoop:
                 if j < SAMPLE_CALLS:
                     self.samples[j] = (k, images)
             calls += 1
-            if time.perf_counter() - t_start >= seconds:
+            if time.perf_counter() - t_start >= seconds or calls == max_calls:
                 return calls
 
     def compared(self):
@@ -273,7 +281,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     ``t_process`` is the host clock (``time.perf_counter``) at process
     start, so ``setup_s`` counts loading JAX and reaching the chip too.
     ``factory`` replaces the system under test (tests, the control)."""
-    from sarbench import trace as tracelib
+    from sarbench import scopes, trace as tracelib
 
     scene = scenelib.scene_from_config(cell.config)
     if cell.traffic["loop"] != "closed":
@@ -285,17 +293,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     try:
         loop.setup()
         setup_s = time.perf_counter() - t_process
-        log(tag, f"set-up {setup_s:.3f} s; window of {seconds} s starts")
+        log(tag, f"set-up {setup_s:.3f} s; window of {seconds} s "
+                 f"{f'or {TRACED_CALLS} calls ' if trace else ''}starts")
         if trace:
             with tracelib.recording() as rec:
-                w = loop.run(seconds, counter)
-            tr = rec.load(SPAN_NAMES)
+                w = loop.run(seconds, counter, max_calls=TRACED_CALLS)
+            tr = scopes.ScopedTrace.from_xplane(rec.path(), SPAN_NAMES)
         else:
             w = loop.run(seconds, counter)
         mem = memory_peak_bytes(devices[0])
     finally:
         loop.close()            # the program's state goes before the check
-    log(tag, f"window {w.seconds:.3f} s: {w.attempted} attempted, "
+    log(tag, f"window {w.seconds:.3f} s, {w.calls} calls: "
+             f"{w.attempted} attempted, "
              f"{w.failed} failed, {w.scenes} images on the host, "
              f"{w.compiles} compiles inside the window")
     check = compare(loop, scene, cell.config, tag)
@@ -326,7 +336,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         "l2_rel_by_range_band": check["profile"],
         "images_compared": check["images"],
         "compiles_in_window": w.compiles,
-        "window_s": w.seconds, "setup_s": setup_s}
+        "window_s": w.seconds, "setup_s": setup_s,
+        # after the readers, which hold the parsed profile
+        "host_rss_peak_bytes":
+            resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024}
+    if trace:
+        result["diagnostics"]["traced_calls"] = w.calls
     result["compared"] = {
         "l2_rel": {"value": check["l2_rel"], "limit": check["limit"]},
         "failed": {"value": w.failed, "limit": 0}}

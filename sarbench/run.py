@@ -8,8 +8,12 @@ name under ``sarbench/`` (see ``sarbench/README.md``, and ``spec.py`` for
 the layout). The last line of standard output is one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the end-to-end
 metrics with ``--trace 0``, the per-layer ones with ``--trace 1``),
-``device`` and, traced, ``breakdown``, then ``compared``: each number the
-correctness check compared, with its limit. Standard error ends with the
+``device`` and, traced, ``breakdown``, then ``diagnostics`` and
+``compared``: each number the correctness check compared, with its limit.
+A traced run measures at most ``harness.TRACED_CALLS`` calls or
+``--seconds``, whichever ends first, so its per-layer metrics and
+``breakdown`` are those of at most that many calls; an untraced run
+measures the whole ``--seconds``. Standard error ends with the
 same numbers. Every line on standard error names the platform, the device
 kind and the device count. A host where JAX finds no accelerator, or fewer
 chips than the cell asks for, exits non-zero and prints no result.

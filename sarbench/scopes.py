@@ -1,8 +1,8 @@
 """Plan steps and the runtime's copy work, read from a traced run's profile.
 
 ``trace.Trace`` keeps each device operation's instruction name and the
-benchmark's own host spans. ``ScopedTrace`` reads the same profile for two
-things more, in fields of its own:
+benchmark's own host spans. ``ScopedTrace`` keeps those and two things
+more, in fields of its own:
 
 - ``named``: each device operation's ``op_name`` metadata. A v5e trace
   keeps it in the ``tf_op`` stat of the event's metadata, which
@@ -27,9 +27,12 @@ converting between complex64 and f32 planes (``glue_seconds``), and the
 share of the copy spans in which some host thread converts a layout
 (``host_share``).
 
-``for_run`` finds the profile of a traced run: ``trace.recording`` keeps
-it under ``TMPDIR``, in a directory named ``sarbench_trace_*``, until the
-process ends; the one whose ``window`` span is the run's is read.
+A traced run reads its profile once, with ``ScopedTrace.from_xplane``,
+and the per-layer readers take that reading from the run (``for_run``).
+Given a plain ``trace.Trace``, ``for_run`` finds its profile under
+``TMPDIR``, where ``trace.recording`` keeps it in a directory named
+``sarbench_trace_*`` until the process ends: the one whose ``window`` span
+is the trace's is read.
 """
 from __future__ import annotations
 
@@ -183,6 +186,53 @@ def _xspace_class():
         pool.FindMessageTypeByName(f"{pkg}.XSpace"))
 
 
+def _times(line, event) -> tuple:
+    """(start, end) of an event: whole ns, as ``jax.profiler.ProfileData``
+    gives them."""
+    start = float(line.timestamp_ns + event.offset_ps // 1000)
+    return start, start + event.duration_ps // 1000
+
+
+def _device_events(plane) -> tuple:
+    """The operations of a chip plane's ``XLA Ops`` line, as
+    ``([(instruction name, start, end)], [(op_name or None, start, end)])``.
+    """
+    stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+    metadata = plane.event_metadata
+    ops, named = [], []
+    for line in plane.lines:
+        if line.name != T.OPS_LINE:
+            continue
+        for e in line.events:
+            start, end = _times(line, e)
+            md = metadata[e.metadata_id]
+            ops.append((T.op_name(md.name), start, end))
+            named.append((_stat(OP_NAME_STAT, (e.stats, md.stats),
+                                stat_names), start, end))
+    return ops, named
+
+
+def _host_events(plane, span_names: set) -> tuple:
+    """The host plane's spans named in ``span_names`` and its copy work,
+    as ``([(name, start, end)], [(kind, thread, start, end)])``."""
+    spans, host = [], []
+    wanted = set(RELAYOUT) | set(TRANSFER)
+    names = {k: v.name for k, v in plane.event_metadata.items()}
+    # a host plane holds about 600,000 tile transposes a call: pick the
+    # wanted names by metadata id before the events
+    span_ids = {k: n for k, n in names.items() if n in span_names}
+    kinds = {k: event_kind(n) for k, n in names.items()
+             if event_kind(n) in wanted}
+    for line in plane.lines:
+        for e in line.events:
+            if e.metadata_id in span_ids:
+                spans.append((span_ids[e.metadata_id], *_times(line, e)))
+            elif e.metadata_id in kinds:
+                host.append((kinds[e.metadata_id], line.name,
+                             *_times(line, e)))
+    return spans, host
+
+
 @dataclasses.dataclass
 class ScopedTrace(T.Trace):
     """A ``trace.Trace`` plus each device operation's ``op_name`` and the
@@ -195,41 +245,22 @@ class ScopedTrace(T.Trace):
 
     @classmethod
     def from_xplane(cls, path: str, span_names: set) -> "ScopedTrace":
-        base = T.Trace.from_xplane(path, span_names)
-        wanted = set(RELAYOUT) | set(TRANSFER)
+        """One walk of the profile file: the device operations and host
+        spans ``trace.Trace.from_xplane`` keeps, and this class's own
+        fields."""
+        ops: dict = {}
         named: dict = {}
-        host = []
+        spans, host = [], []
         with open(path, "rb") as f:
             space = _xspace_class().FromString(f.read())
         for plane in space.planes:
-            if plane.name not in base.ops and plane.name != T.HOST_PLANE:
-                continue
-            stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
-            metadata = plane.event_metadata
-            device = plane.name in base.ops
-            # a host plane holds about 600,000 tile transposes a call:
-            # pick the wanted kinds by metadata id before the events
-            kinds = {k: event_kind(v.name) for k, v in metadata.items()}
-            kinds = {k: v for k, v in kinds.items() if v in wanted}
-            for line in plane.lines:
-                if device and line.name != T.OPS_LINE:
-                    continue
-                for e in line.events:
-                    if not device and e.metadata_id not in kinds:
-                        continue
-                    # whole ns, as jax.profiler.ProfileData gives them
-                    start = float(line.timestamp_ns + e.offset_ps // 1000)
-                    end = start + e.duration_ps // 1000
-                    if device:
-                        md = metadata[e.metadata_id]
-                        named.setdefault(plane.name, []).append(
-                            (_stat(OP_NAME_STAT, (e.stats, md.stats),
-                                   stat_names), start, end))
-                    else:
-                        host.append((kinds[e.metadata_id], line.name, start,
-                                     end))
-        return cls(base.ops, base.spans, base.start_ns, base.end_ns,
-                   named, host)
+            if T.is_device_plane(plane.name):
+                ops[plane.name], chip_named = _device_events(plane)
+                if chip_named:
+                    named[plane.name] = chip_named
+            elif plane.name == T.HOST_PLANE:
+                spans, host = _host_events(plane, span_names)
+        return cls.from_events(ops, spans, named=named, host=host)
 
     # -- device operations by scope -----------------------------------------
     def programs(self) -> set:
@@ -292,8 +323,9 @@ def _load(path: str, mtime: float) -> ScopedTrace:
 
 
 def for_run(run) -> Optional[ScopedTrace]:
-    """The scoped reading of a traced run's profile, or None where the run
-    was not traced or its profile is gone."""
+    """The scoped reading of a traced run's profile: the run's own trace
+    where it is one, else found under TMPDIR; None where the run was not
+    traced or its profile is gone."""
     if run.trace is None or isinstance(run.trace, ScopedTrace):
         return run.trace
     files = glob.glob(os.path.join(tempfile.gettempdir(), RECORDING_GLOB),

@@ -95,7 +95,9 @@ def test_new_files_alone_make_a_cell(layout):
     names = [m.name for m, _ in cell.per_layer]
     assert "window_length" in names
     out = run(layout, "tiny.closed2", trace=True)
-    assert out["metrics"]["window_length"]["value"] >= 1.0
+    # the traced window ends after TRACED_CALLS calls or its seconds
+    assert out["metrics"]["window_length"]["value"] == \
+        out["diagnostics"]["window_s"] > 0.0
     assert out["metrics"]["transfer_share.batch"]["unit"] == "%"
 
 
@@ -107,6 +109,36 @@ def test_closed_loop_sound_run(layout):
     assert out["device"]["count"] >= 1
     assert list(out)[-1] == "compared"
     assert out["compared"]["l2_rel"]["value"] < 1e-6
+
+
+# -- the traced window's cap ---------------------------------------------------
+
+def _reference(config):
+    """A fast and correct entry: the plain reference."""
+    from sarbench import reference, scene
+
+    return reference.Reference(scene.scene_from_config(config))
+
+
+def test_a_traced_window_stops_at_its_cap(layout, monkeypatch):
+    monkeypatch.setattr(harness, "TRACED_CALLS", 3)
+    out = run(layout, "tiny.closed2", trace=True, factory=_reference,
+              seconds=120.0)
+    d = out["diagnostics"]
+    assert d["traced_calls"] == 3 and d["window_s"] < 120.0
+    assert out["attempted"] == 3 * CLOSED["batch"]
+    assert out["correct"] is True, out["compared"]
+    assert d["host_rss_peak_bytes"] > 0
+
+
+def test_an_untraced_window_runs_past_the_cap(layout, monkeypatch):
+    monkeypatch.setattr(harness, "TRACED_CALLS", 3)
+    out = run(layout, "tiny.closed2", factory=_reference, seconds=2.0)
+    d = out["diagnostics"]
+    assert out["attempted"] > 3 * CLOSED["batch"]
+    assert d["window_s"] >= 2.0 and "traced_calls" not in d
+    assert d["host_rss_peak_bytes"] > 0
+    assert out["correct"] is True, out["compared"]
 
 
 # -- what must come out not correct -----------------------------------------

@@ -175,14 +175,24 @@ def recorded_file(tmp_path, monkeypatch):
     return write_profile(tmp_path, monkeypatch)
 
 
-def test_profile_file_keeps_the_base_reading_and_adds_names(recorded_file):
+def same_as_base(path):
+    """The one walk of a profile file, checked against the reading through
+    ``jax.profiler.ProfileData``."""
     from sarbench.harness import SPAN_NAMES
 
-    base = T.Trace.from_xplane(str(recorded_file), SPAN_NAMES)
-    t = S.ScopedTrace.from_xplane(str(recorded_file), SPAN_NAMES)
+    base = T.Trace.from_xplane(str(path), SPAN_NAMES)
+    t = S.ScopedTrace.from_xplane(str(path), SPAN_NAMES)
     assert (t.ops, t.spans, t.start_ns, t.end_ns) == \
         (base.ops, base.spans, base.start_ns, base.end_ns)
     assert t.breakdown() == base.breakdown()
+    assert (t.busy_seconds(), t.idle_share()) == \
+        (base.busy_seconds(), base.idle_share())
+    return t
+
+
+def test_profile_file_keeps_the_base_reading_and_adds_names(
+        recorded_file, tmp_path, monkeypatch):
+    t = same_as_base(recorded_file)
     # op names from the event metadata, from an event's own stat, interned
     assert t.named[CHIP] == [
         ("raw", 1000, 1004),
@@ -199,6 +209,36 @@ def test_profile_file_keeps_the_base_reading_and_adds_names(recorded_file):
         ("tpu::System::TransferFromDevice", "tpu_worker_1", 1045, 1050)]
     assert t.glue_seconds() == pytest.approx(8e-9)
     assert t.seconds_by_step()["azimuth_fft"] == pytest.approx(20e-9)
+    # the call recorded on a v5e, as a profile file: the same reading as
+    # the base's, and every scoped reading equal to the last digit
+    kept, _ = recorded_call()
+    t = same_as_base(write_profile(tmp_path / "call", monkeypatch,
+                                   recorded_call_xspace()))
+    assert (t.ops, t.spans, t.start_ns, t.end_ns, t.named) == \
+        (kept.ops, kept.spans, kept.start_ns, kept.end_ns, kept.named)
+    assert sorted(t.host) == sorted(kept.host)
+    assert t.breakdown() == kept.breakdown()
+    assert t.seconds_by_step() == kept.seconds_by_step()
+    assert t.glue_seconds() == kept.glue_seconds()
+    assert t.host_share(S.RELAYOUT, S.COPY_SPANS) == \
+        kept.host_share(S.RELAYOUT, S.COPY_SPANS)
+
+
+def test_a_traced_run_keeps_its_own_reading(recorded_file, monkeypatch):
+    """A run whose trace is already scoped is read as it is: no profile is
+    looked for under TMPDIR, nor parsed again."""
+    from sarbench.harness import SPAN_NAMES
+
+    t = S.ScopedTrace.from_xplane(str(recorded_file), SPAN_NAMES)
+
+    def no_search(*args, **kw):
+        raise AssertionError("searched for a profile")
+    monkeypatch.setattr(S.glob, "glob", no_search)
+    monkeypatch.setattr(S, "_load", no_search)
+    run = types.SimpleNamespace(trace=t)
+    assert S.for_run(run) is t
+    assert spec.load_reader("complex_glue_share.batch")(run) == \
+        pytest.approx(100 * 8 / 25)
 
 
 def test_readers_find_the_run_by_its_window(recorded_file):
@@ -235,6 +275,48 @@ def recorded_call():
     t = S.ScopedTrace(ops, [tuple(s) for s in doc["spans"]], *doc["window"],
                       named=named, host=[tuple(h) for h in doc["host"]])
     return t, doc
+
+
+def recorded_call_xspace():
+    """The recorded call as the text of a profile it could come from: one
+    event metadata entry per operation, named as a v5e names it, with its
+    ``op_name`` in the ``tf_op`` stat; the host spans and copy work on
+    their threads, with the ``window`` span around them."""
+    _, doc = recorded_call()
+
+    def event(key, s, e):
+        # with sub-ns parts, which whole ns drop
+        return (f"events {{ metadata_id: {key} "
+                f"offset_ps: {int(s) * 1000 + 999} "
+                f"duration_ps: {(int(e) - int(s)) * 1000 + 1} }}")
+
+    ops, op_md = [], []
+    for key, (n, o, s, e) in enumerate(doc["ops"], 1):
+        ops.append(event(key, s, e))
+        stat = "" if o is None else \
+            f"stats {{ metadata_id: 1 str_value: {json.dumps(o)} }}"
+        name = f"%{n}.{key} = f32[4,4096,4096]{{2,1,0}} {n}()"
+        op_md.append(f"event_metadata {{ key: {key} value {{ id: {key} "
+                     f"name: {json.dumps(name)} {stat} }} }}")
+    threads: dict = {"python": [event(1, *doc["window"])]}
+    names = ["window"]
+    for n, s, e in doc["spans"]:
+        names.append(n)
+        threads["python"].append(event(len(names), s, e))
+    for k, thread, s, e in doc["host"]:
+        names.append(k + (" c64[4,4096,4096]{2,1,0}"
+                          if k == "X64FromTuple" else ""))
+        threads.setdefault(thread, []).append(event(len(names), s, e))
+    lines = [f"lines {{ name: {json.dumps(t)} timestamp_ns: 0 "
+             f"{' '.join(evs)} }}" for t, evs in threads.items()]
+    host_md = [f"event_metadata {{ key: {k} value {{ id: {k} "
+               f"name: {json.dumps(n)} }} }}" for k, n in enumerate(names, 1)]
+    return (f'planes {{ id: 1 name: "{CHIP}" '
+            f'lines {{ name: "{T.OPS_LINE}" timestamp_ns: 0 {" ".join(ops)} }}'
+            f' {" ".join(op_md)} '
+            f'stat_metadata {{ key: 1 value {{ id: 1 name: "tf_op" }} }} }}\n'
+            f'planes {{ id: 2 name: "{T.HOST_PLANE}" {" ".join(lines)} '
+            f'{" ".join(host_md)} }}\n')
 
 
 def grid(doc, intervals):

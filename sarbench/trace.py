@@ -1,7 +1,7 @@
 """The reduction from a profiler trace to the benchmark's numbers.
 
-A traced run records the whole measured window with ``jax.profiler``
-(Python tracing off). ``Trace.from_xplane`` keeps two kinds of events:
+A traced run records its measured window with ``jax.profiler`` (Python
+tracing off). ``Trace.from_xplane`` keeps two kinds of events:
 
 - device operations: the events of the ``XLA Ops`` line of every TPU
   plane (each HLO operation and Pallas kernel as it ran on the chip);
@@ -16,6 +16,10 @@ From those:
 - the top device operations are summed by name;
 - each idle gap of the device is split by the host span open during it,
   and summed by that span's name (``none`` where no span was open).
+
+A run reads its profile once, with ``scopes.ScopedTrace.from_xplane``,
+which keeps these events and more; ``Trace.from_xplane`` reads them
+through ``jax.profiler.ProfileData``, the reading it is tested against.
 """
 from __future__ import annotations
 
@@ -34,6 +38,12 @@ DEVICE_PLANE_PREFIX = "/device:TPU:"
 HOST_PLANE = "/host:CPU"
 NO_SPAN = "none"
 WINDOW_SPAN = "window"
+
+
+def is_device_plane(name: str) -> bool:
+    """``/device:TPU:<n>``: one chip's plane."""
+    return name.startswith(DEVICE_PLANE_PREFIX) and \
+        name[len(DEVICE_PLANE_PREFIX):].isdigit()
 
 
 def op_name(hlo: str) -> str:
@@ -92,8 +102,7 @@ class Trace:
         ops: dict = {}
         spans = []
         for plane in pd.planes:
-            if plane.name.startswith(DEVICE_PLANE_PREFIX) and \
-                    plane.name[len(DEVICE_PLANE_PREFIX):].isdigit():
+            if is_device_plane(plane.name):
                 evs = ops.setdefault(plane.name, [])
                 for line in plane.lines:
                     if line.name == OPS_LINE:
@@ -106,6 +115,12 @@ class Trace:
                                   e.start_ns + e.duration_ns)
                                  for e in line.events
                                  if e.name in span_names)
+        return cls.from_events(ops, spans)
+
+    @classmethod
+    def from_events(cls, ops: dict, spans: list, **fields) -> "Trace":
+        """The trace bounded by the one ``window`` span among ``spans``;
+        ``fields`` are a subclass's own."""
         windows = [s for s in spans if s[0] == WINDOW_SPAN]
         if len(windows) != 1:
             raise ValueError(f"the trace holds {len(windows)} "
@@ -117,7 +132,7 @@ class Trace:
                              "window: the device and host clocks disagree, "
                              "or nothing ran on the chip")
         return cls(ops, [s for s in spans if s[0] != WINDOW_SPAN],
-                   start, end)
+                   start, end, **fields)
 
     # -- reductions ----------------------------------------------------------
     def window_seconds(self) -> float:
@@ -179,12 +194,13 @@ class Recording:
     def __init__(self, directory: str):
         self.directory = directory
 
-    def load(self, span_names: set) -> Trace:
+    def path(self) -> str:
+        """The one profile file the recording wrote."""
         files = glob.glob(os.path.join(self.directory, "**", "*.xplane.pb"),
                           recursive=True)
         if len(files) != 1:
             raise RuntimeError(f"expected one trace file, found {files}")
-        return Trace.from_xplane(files[0], span_names)
+        return files[0]
 
 
 @contextlib.contextmanager
