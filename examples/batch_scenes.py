@@ -64,12 +64,13 @@ def main():
     jax.block_until_ready(imgs_b)
     t_batch = time.perf_counter() - t0
 
-    err = float(jnp.max(jnp.abs(imgs_b - jnp.stack(imgs_seq))))
+    imgs_b = np.asarray(imgs_b)                      # the host copy
+    err = float(np.max(np.abs(imgs_b - np.asarray(jnp.stack(imgs_seq)))))
     print(f"batched vs per-scene max abs diff: {err:.3e}")
     assert err == 0.0, f"batched focusing diverged from per-scene: {err}"
 
     for i in range(args.batch):
-        reps = metrics.analyze_scene(np.asarray(imgs_b[i]), cfg, targets)
+        reps = metrics.analyze_scene(imgs_b[i], cfg, targets)
         worst = min(r.snr_db for r in reps)
         print(f"scene {i}: worst target SNR {worst:.1f} dB")
 

@@ -71,6 +71,7 @@ from repro.kernels.fft4step import (
     resolve_factors,
     resolve_precision,
 )
+from repro.kernels.interleave import interleave as interleave_planes
 from repro.kernels.transpose import transpose as tiled_transpose
 from repro.tuning import KernelConfig, Schedule, SegmentConfig, cached_config
 
@@ -100,6 +101,65 @@ def split(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 def unsplit(xr: jnp.ndarray, xi: jnp.ndarray) -> jnp.ndarray:
     with jax.named_scope("unsplit"):
         return xr.astype(jnp.complex64) + 1j * xi.astype(jnp.complex64)
+
+
+def interleave(xr: jnp.ndarray, xi: jnp.ndarray,
+               interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Two f32 planes ``(..., n)`` -> one f32 array ``(..., 2n)`` whose
+    elements alternate real and imaginary part: complex64's own byte
+    layout, so its host copy views as complex64 with nothing joined.
+    The image's way out of the jitted pipeline, under ``unsplit``."""
+    with jax.named_scope("unsplit"):
+        return interleave_planes(xr, xi, interpret=interpret)
+
+
+@jax.jit
+def _deinterleave(x: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.complex(x[..., 0::2], x[..., 1::2])
+
+
+class FocusedImages:
+    """Focused images as :meth:`Pipeline.jitted` hands them back.
+
+    On the device they are one f32 array ``interleaved`` of shape
+    ``(..., na, 2·nr)``, real and imaginary parts alternating along range.
+    To the host they are complex64 ``(..., na, nr)``: ``np.asarray`` copies
+    the f32 array (the runtime's plain relayout) and views it as
+    complex64, so the copy back skips the one-thread join of the two
+    halves of each complex64 element that a complex64 result costs. The
+    copy happens there and nowhere else. :meth:`on_device` (also
+    ``jnp.asarray``) gives the complex64 device array, and any other
+    ``jax.Array`` attribute (``.at``, ``.conj()``, ...) is read from it."""
+
+    dtype = np.dtype(np.complex64)
+
+    def __init__(self, interleaved: jax.Array):
+        self.interleaved = interleaved
+
+    @property
+    def shape(self) -> tuple:
+        *lead, n2 = self.interleaved.shape
+        return (*lead, n2 // 2)
+
+    def block_until_ready(self) -> "FocusedImages":
+        self.interleaved.block_until_ready()
+        return self
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        img = np.asarray(self.interleaved).view(np.complex64)
+        if copy or (dtype is not None and np.dtype(dtype) != img.dtype):
+            return np.array(img, dtype=dtype)
+        return img
+
+    def on_device(self) -> jax.Array:
+        return _deinterleave(self.interleaved)
+
+    __jax_array__ = on_device
+
+    def __getattr__(self, name: str):
+        if name.startswith("__"):     # numpy and copy probe for protocols
+            raise AttributeError(name)
+        return getattr(self.on_device(), name)
 
 
 # ---------------------------------------------------------------------------
@@ -516,6 +576,9 @@ class Step:
     # (physical axis, length, factors) of the transform a per-axis Pallas
     # dispatch runs; None for any other step
     transform: Optional[tuple] = None
+    # Pallas spectral and mega steps: fn's two f32 result planes, before
+    # unsplit joins them (the jitted pipeline interleaves them instead)
+    planes: Optional[Callable] = None
 
 
 @dataclasses.dataclass
@@ -574,26 +637,53 @@ class Pipeline:
         bit-for-bit (asserted in tests/test_service.py). Steps execute
         eagerly; wrap with :meth:`jitted` to fuse the inter-step glue.
         """
+        return self._run(raw)
+
+    def _run(self, raw: jnp.ndarray, interleaved: bool = False):
+        """The steps in order, each under its own scope; ``interleaved``
+        returns the image as :func:`interleave` of the last step's f32
+        planes (its complex result split where it has no planes)."""
         x = raw
-        for s in self.steps:
+        last = len(self.steps) - 1
+        for i, s in enumerate(self.steps):
             with jax.named_scope(s.name):
-                x = s.fn(x)
+                if not (interleaved and i == last):
+                    x = s.fn(x)
+                elif s.planes is not None:
+                    x = interleave(*s.planes(x),
+                                   interpret=s.kernel_kw["interpret"])
+                else:
+                    x = interleave(*split(s.fn(x)))
         return x
 
-    def jitted(self) -> Callable[[jnp.ndarray], jnp.ndarray]:
-        """One jax.jit callable for the whole step sequence. Retraces per
+    def jitted(self) -> Callable[[jnp.ndarray], FocusedImages]:
+        """One jax.jit program for the whole step sequence. Retraces per
         distinct input shape (each batch size B is one trace); the
         focusing service pre-traces its micro-batch sizes at warm-up.
 
+        The image contract: the program takes complex64 ``(..., na, nr)``
+        and returns one f32 array ``(..., na, 2·nr)``, the last step's
+        two f32 planes interleaved (:func:`interleave`) with no complex64
+        join. The returned callable wraps it as :class:`FocusedImages`,
+        complex64 ``(..., na, nr)`` to the host: the copy back happens in
+        ``np.asarray``, as a plain f32 copy viewed as complex64, and the
+        host image equals :meth:`run`'s. ``.lower`` lowers the program.
+
         The function is named ``focus_<pipeline name>`` (HLO module
         ``jit_focus_<name>``) and each step's operations carry the step's
-        name as their scope, so a profile attributes each device
-        operation to a plan step; the compiler's own split of the
-        complex argument where it enters carries the argument's name."""
+        name as their scope (the interleave its last step's ``unsplit``),
+        so a profile attributes each device operation to a plan step; the
+        compiler's own split of the complex argument where it enters
+        carries the argument's name."""
         def f(raw):
-            return self.run(raw)
+            return self._run(raw, interleaved=True)
         f.__name__ = f.__qualname__ = f"focus_{self.name}"
-        return jax.jit(f)
+        program = jax.jit(f)
+
+        def focus(raw) -> FocusedImages:
+            return FocusedImages(program(raw))
+        focus.lower = program.lower
+        return focus
 
     def lower_sharded(self, mesh, axes=("data",), **kw):
         """Lower this compiled pipeline onto a device mesh: every
@@ -779,12 +869,15 @@ def _make_spectral_step(group, mode, arrays, *, cfg, transposed, backend,
         transform = (phys_axis, n, _transform_factors(n, kernel_kw))
         scope = dispatch_scope(n, transform[2])
 
+    planes = None
     if backend == BACKEND_PALLAS:
-        def fn(x, _fk=filter_kw):
+        def planes(x, _fk=filter_kw):
             xr, xi = split(x)
             with jax.named_scope(scope):
-                yr, yi = ops.spectral_op(xr, xi, **_fk, **kernel_kw)
-            return unsplit(yr, yi)
+                return ops.spectral_op(xr, xi, **_fk, **kernel_kw)
+
+        def fn(x):
+            return unsplit(*planes(x))
     else:
         # the unfused oracle: same math, one jnp op per piece
         def fn(x, _fk=filter_kw):
@@ -810,7 +903,7 @@ def _make_spectral_step(group, mode, arrays, *, cfg, transposed, backend,
     return Step(name, fn, 1, 1, fused, stream_axis, strip_fn,
                 kind="spectral", phys_axis=phys_axis, filter_mode=mode,
                 filter_kw=filter_kw, kernel_kw=kernel_kw,
-                transform=transform)
+                transform=transform, planes=planes)
 
 
 def _transform_factors(n: int, kernel_kw: dict) -> tuple:
@@ -918,11 +1011,14 @@ def _make_mega_step(group, seg_payloads, *, cfg, backend, opts) -> Step:
     if tuned.buffer_depth is not None:
         kernel_kw["buffer_depth"] = tuned.buffer_depth
 
+    planes = None
     if backend == BACKEND_PALLAS:
-        def fn(x, _fa=tuple(filter_args)):
+        def planes(x, _fa=tuple(filter_args)):
             xr, xi = split(x)
-            yr, yi = ops.mega_spectral_op(xr, xi, *_fa, **kernel_kw)
-            return unsplit(yr, yi)
+            return ops.mega_spectral_op(xr, xi, *_fa, **kernel_kw)
+
+        def fn(x):
+            return unsplit(*planes(x))
     else:
         # the unfused oracle: the same segment chain, one jnp op per piece
         def fn(x, _sf=tuple(seg_fk)):
@@ -947,7 +1043,8 @@ def _make_mega_step(group, seg_payloads, *, cfg, backend, opts) -> Step:
     # (bench rows carry residency=... so the distinction stays visible).
     return Step(name, fn, 1, 1, fused, None, None, kind="mega",
                 phys_axis=None, filter_mode=MEGA, filter_kw=None,
-                kernel_kw=kernel_kw, seg_filter_args=tuple(seg_args))
+                kernel_kw=kernel_kw, seg_filter_args=tuple(seg_args),
+                planes=planes)
 
 
 def _xla_apply(x, fwd, inv, mode, fk, phys_axis):

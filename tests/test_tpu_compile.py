@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.sar import SceneConfig, build_pipeline, paper_scene
 from repro.kernels import fft4step, ops
+from repro.kernels.interleave import interleave
 from repro.tuning import cost
 
 V5E = "TPU v5 lite"
@@ -101,6 +102,17 @@ def test_fused3_mixed_radix_dispatch_compiles_for_v5e(on_v5e, one_chip,
     x = jax.ShapeDtypeStruct((1, ERS_LINES.na, ERS_LINES.nr), jnp.complex64,
                              sharding=one_chip)
     _compile(pipe.steps[step].fn, x)
+
+
+@pytest.mark.parametrize("shape", [(4, N, N), (1, 8192, 6144)],
+                         ids=["paper_batch4", "ers_block"])
+def test_image_interleave_compiles_for_v5e(on_v5e, one_chip, shape):
+    """The kernel that writes the focused image as interleaved f32, at
+    both cells' image sizes."""
+    f32 = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = _compile(lambda xr, xi: interleave(xr, xi, interpret=False),
+                    f32, f32)
+    assert f"f32[{shape[0] * shape[1]},{2 * shape[2]}]" in text
 
 
 def test_range_dispatch_shared_filter_compiles_for_v5e(on_v5e, one_chip):
